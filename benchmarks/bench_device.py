@@ -92,9 +92,14 @@ def _machine_fingerprint() -> str:
     return f"{model} x{os.cpu_count()}"
 
 
+# the mesh measurement runs on two FORCED HOST (CPU) devices in a child
+# process — the device count can only be forced before jax initializes.  The
+# child pins itself to the CPU platform, so it never competes with the parent
+# for an accelerator, and its numbers are labelled as CPU numbers.
 _MESH_BENCH_SCRIPT = r"""
 import json, os, time
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+os.environ["JAX_PLATFORMS"] = "cpu"
 import numpy as np
 import jax
 from repro.core.device_simulate import simulate_trace
@@ -128,6 +133,7 @@ simulate_trace(tr, 8192, mesh=mesh, mesh_exchange="stale", **kw)
 s_wall, rs = best_of(lambda: simulate_trace(tr, 8192, mesh=mesh,
                                             mesh_exchange="stale", **kw))
 print(json.dumps({
+    "device": jax.devices()[0].platform,
     "mesh_devices": len(jax.devices()),
     "accesses": n,
     "sharded_1dev_acc_per_s": round(n / sh_wall),
@@ -141,26 +147,20 @@ print(json.dumps({
 """
 
 
-def _mesh_subprocess_bench(quick: bool) -> dict | None:
-    """Run the 2-forced-host-device mesh measurement; None on failure (the
-    snapshot then simply omits the mesh_* fields, which check_bench
-    tolerates — pre-mesh snapshots look the same)."""
+def _mesh_subprocess_bench(quick: bool) -> dict:
+    """Run the 2-forced-host-device mesh measurement in a CPU-only child;
+    a failed or timed-out child fails the benchmark."""
     import subprocess
     import sys
-    env = dict(os.environ, PYTHONPATH=os.path.join(_REPO_ROOT, "src"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(_REPO_ROOT, "src"),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)          # the script pins its own device count
-    try:
-        r = subprocess.run(
-            [sys.executable, "-c",
-             _MESH_BENCH_SCRIPT % {"n": 15_000 if quick else 30_000}],
-            capture_output=True, text=True, env=env, timeout=1800)
-    except subprocess.TimeoutExpired:
-        print("  mesh bench: subprocess timed out — skipping", flush=True)
-        return None
+    r = subprocess.run(
+        [sys.executable, "-c",
+         _MESH_BENCH_SCRIPT % {"n": 15_000 if quick else 30_000}],
+        capture_output=True, text=True, env=env, timeout=1800)
     if r.returncode != 0:
-        print("  mesh bench: subprocess failed — skipping\n"
-              + r.stderr[-500:], flush=True)
-        return None
+        raise RuntimeError("mesh bench child failed:\n" + r.stderr[-2000:])
     return json.loads(r.stdout.strip().splitlines()[-1])
 
 
@@ -253,8 +253,11 @@ def run(quick: bool = False):
         adm.record(k)
     host_rec = time.perf_counter() - t0
     cfg = make_config(C, sample_factor=8, counters_per_item=1.0)
-    use_pallas = backend == "tpu"    # jnp oracle off-TPU: same bits, no
-    clo, chi = keys_to_lanes(cands)  # interpret-mode overhead
+    # the XLA path on every backend: off the TPU the Pallas kernels only
+    # interpret, so this section times the same program everywhere (the
+    # kernels' chip run is chip_smoke.py phase d)
+    use_pallas = False
+    clo, chi = keys_to_lanes(cands)
     vlo, vhi = keys_to_lanes(victims)
     state = ops.add(cfg, init_state(cfg), clo, chi, use_pallas)
     jax.block_until_ready(state["counters"])
@@ -370,20 +373,20 @@ def run(quick: bool = False):
 
     # -- 7. multi-device mesh run (ISSUE 5/6): 2 forced host devices ---------
     # forcing the host device count only works before jax initializes, so
-    # the mesh measurement runs in a subprocess: single-device sharded,
-    # exact chunked-exchange mesh, and speculative stale-global mesh on the
-    # same trace in the same environment, reporting throughput + bitwise
-    # parity of the chunked hit sequence.
+    # the mesh measurement runs in a CPU-only subprocess: single-device
+    # sharded, exact chunked-exchange mesh, and speculative stale-global mesh
+    # on the same trace in the same environment, reporting throughput +
+    # bitwise parity of the chunked hit sequence.  Its "device" is the
+    # child's platform (cpu), whatever the parent runs on.
     mesh = _mesh_subprocess_bench(quick)
-    if mesh:
-        rows.append({"trace": "golden-zipf", "engine": "mesh(s=4,d=2)",
-                     **mesh, "device": backend})
-        print(f"  mesh(s=4,d=2)    C=8192 {mesh['mesh_acc_per_s']:>12,.0f} "
-              f"acc/s ({mesh['mesh_overhead_vs_sharded']:.1f}x sharded cost, "
-              f"parity {'OK' if mesh['parity_ok'] else 'BROKEN'}; stale "
-              f"{mesh['mesh_stale_acc_per_s']:,.0f} acc/s, "
-              f"{mesh['mesh_stale_overhead_vs_sharded']:.1f}x)",
-              flush=True)
+    rows.append({"trace": "golden-zipf", "engine": "mesh(s=4,d=2)", **mesh})
+    print(f"  mesh(s=4,d=2,{mesh['device']}) C=8192 "
+          f"{mesh['mesh_acc_per_s']:>12,.0f} "
+          f"acc/s ({mesh['mesh_overhead_vs_sharded']:.1f}x sharded cost, "
+          f"parity {'OK' if mesh['parity_ok'] else 'BROKEN'}; stale "
+          f"{mesh['mesh_stale_acc_per_s']:,.0f} acc/s, "
+          f"{mesh['mesh_stale_overhead_vs_sharded']:.1f}x)",
+          flush=True)
 
     # -- 8. checkpoint overhead (ISSUE 7): epoch-boundary snapshot cost ------
     # same config as the section-6 sharded baseline (assoc=8, shards=4,
@@ -521,18 +524,17 @@ def run(quick: bool = False):
         "policy_acc_per_s_arc": round(pol_acc["arc"]),
         "policy_acc_per_s_lfu": round(pol_acc["lfu"]),
     }
-    if mesh:
-        snapshot["mesh_devices"] = mesh["mesh_devices"]
-        snapshot["mesh_acc_per_s_8192"] = round(mesh["mesh_acc_per_s"])
-        snapshot["mesh_chunked_acc_per_s_8192"] = round(
-            mesh["mesh_chunked_acc_per_s"])
-        snapshot["mesh_stale_acc_per_s_8192"] = round(
-            mesh["mesh_stale_acc_per_s"])
-        snapshot["mesh_overhead_vs_sharded"] = round(
-            mesh["mesh_overhead_vs_sharded"], 2)
-        snapshot["mesh_stale_overhead_vs_sharded"] = round(
-            mesh["mesh_stale_overhead_vs_sharded"], 2)
-        snapshot["mesh_parity_ok"] = mesh["parity_ok"]
+    snapshot.update({
+        "mesh_device": mesh["device"],
+        "mesh_devices": mesh["mesh_devices"],
+        "mesh_acc_per_s_8192": round(mesh["mesh_acc_per_s"]),
+        "mesh_chunked_acc_per_s_8192": round(mesh["mesh_chunked_acc_per_s"]),
+        "mesh_stale_acc_per_s_8192": round(mesh["mesh_stale_acc_per_s"]),
+        "mesh_overhead_vs_sharded": round(mesh["mesh_overhead_vs_sharded"],
+                                          2),
+        "mesh_stale_overhead_vs_sharded": round(
+            mesh["mesh_stale_overhead_vs_sharded"], 2),
+        "mesh_parity_ok": mesh["parity_ok"]})
     with open(os.path.join(_REPO_ROOT, "BENCH_device.json"), "w") as f:
         json.dump(snapshot, f, indent=1)
 
@@ -541,4 +543,6 @@ def run(quick: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     run()

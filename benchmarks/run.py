@@ -15,6 +15,8 @@ def main() -> None:
     ap.add_argument("--only", type=str, default=None)
     args, _ = ap.parse_known_args()
     quick = not args.full
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
 
     from . import (bench_strawman, bench_zipf, bench_youtube, bench_wiki,
                    bench_traces, bench_window, bench_errors, bench_serving,

@@ -605,7 +605,6 @@ def _mesh_runner(spec: StepSpec, mesh, adaptive: bool):
     if key not in _mesh_cache:
         if len(_mesh_cache) >= _MESH_CACHE_LIMIT:
             _mesh_cache.clear()
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         sspec = _mesh_state_specs(spec)
         chunked = spec.mesh_exchange == "chunk"
@@ -636,9 +635,9 @@ def _mesh_runner(spec: StepSpec, mesh, adaptive: bool):
                 return leave(st, state), jnp.concatenate(
                     [hits.reshape(-1), tail])
 
-            _mesh_cache[key] = jax.jit(shard_map(
+            _mesh_cache[key] = jax.jit(jax.shard_map(
                 fn, mesh=mesh, in_specs=(P(), sspec, P(), P(), P(), P()),
-                out_specs=(sspec, P()), check_rep=False))
+                out_specs=(sspec, P()), check_vma=False))
         else:
             def fn(params, state, los, his, tlo, thi, climb, carry0):
                 st0 = enter(state)
@@ -665,10 +664,10 @@ def _mesh_runner(spec: StepSpec, mesh, adaptive: bool):
                         jnp.concatenate([hits.reshape(-1), tail]),
                         ehits, quotas, jnp.stack(regs))
 
-            _mesh_cache[key] = jax.jit(shard_map(
+            _mesh_cache[key] = jax.jit(jax.shard_map(
                 fn, mesh=mesh,
                 in_specs=(P(), sspec, P(), P(), P(), P(), P(), P()),
-                out_specs=(sspec, P(), P(), P(), P()), check_rep=False))
+                out_specs=(sspec, P(), P(), P(), P()), check_vma=False))
     return _mesh_cache[key]
 
 

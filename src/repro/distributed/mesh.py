@@ -5,6 +5,16 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
+
+
+def _make_mesh(shape, axes, devices):
+    """``jax.make_mesh`` with every axis ``Auto``: the engine and the train
+    step place arrays with ``NamedSharding``/``with_sharding_constraint`` and
+    reshape sharded arrays eagerly, which ``Explicit`` axes (the default
+    since jax 0.7) reject."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,13 +27,13 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {n} devices, found {len(devices)} — set "
             "XLA_FLAGS=--xla_force_host_platform_device_count or run on "
             "real hardware")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _make_mesh(shape, axes, devices[:n])
 
 
 def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for CPU tests (requires >= prod(shape) host devices)."""
     n = math.prod(shape)
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return _make_mesh(shape, axes, jax.devices()[:n])
 
 
 # ---------------------------------------------------------------------------
@@ -98,10 +108,9 @@ def make_shard_mesh(n_shards: int, devices=None, require: int = 0):
                 f"make_shard_mesh(require={require}): {n_shards} shards "
                 "do not split evenly (block placement needs "
                 "shards % devices == 0)")
-        return jax.make_mesh((require,), ("shard",),
-                             devices=devices[:require])
+        return _make_mesh((require,), ("shard",), devices[:require])
     n = _shard_mesh_size(max(1, n_shards), len(devices))
-    return jax.make_mesh((n,), ("shard",), devices=devices[:n])
+    return _make_mesh((n,), ("shard",), devices[:n])
 
 
 def mesh_state_shardings(mesh, state_keys) -> dict:

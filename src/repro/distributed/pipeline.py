@@ -18,7 +18,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(mesh: Mesh, stage_axis: str, block_fn, stacked_params,
@@ -82,7 +81,7 @@ def pipeline_apply(mesh: Mesh, stage_axis: str, block_fn, stacked_params,
 
     params_spec = jax.tree_util.tree_map(
         lambda a: P(stage_axis, *([None] * (a.ndim - 1))), stacked_params)
-    fn = shard_map(stage_body, mesh=mesh,
-                   in_specs=(params_spec, P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(stage_body, mesh=mesh,
+                       in_specs=(params_spec, P()), out_specs=P(),
+                       check_vma=False)
     return fn(stacked_params, x)

@@ -1,8 +1,8 @@
 """Pallas TPU kernels for the TinyLFU sketch hot path + jnp oracles.
 
 Layout (per the kernel deliverable spec):
-  sketch_estimate.py / sketch_update.py / sketch_reset.py / admission.py —
-      pl.pallas_call kernels with explicit BlockSpec/memory-space placement
+  sketch_estimate.py / sketch_update.py — the serving sketch's estimate and
+      sequential add as pl.pallas_call kernels (SMEM key blocks, VMEM tiles)
   sketch_step.py — fused W-TinyLFU simulation step: doorkeeper insert +
       conservative add + candidate/victim estimate + admission verdict +
       window/SLRU table update in ONE VMEM-resident launch per trace chunk

@@ -1,7 +1,10 @@
 """Jitted public wrappers around the sketch kernels.
 
-* pads batches to lane multiples,
-* selects Pallas (TPU) vs interpret-mode Pallas vs the pure-jnp oracle,
+* ``use_pallas`` selects the Pallas kernels (compiled on a TPU, interpret
+  mode elsewhere) or the pure-jnp oracle (ref.py) for ``add`` and
+  ``estimate``; ``admit`` is two estimates and a compare on either path,
+* ``reset`` is always the XLA oracle: one elementwise pass that XLA already
+  runs at memory speed on every platform, so it has no kernel,
 * composes `add` with the automatic reset (paper §3.3: reset once the sample
   counter reaches W).
 
@@ -21,22 +24,11 @@ from . import ref
 from .sketch_common import DeviceSketchConfig, init_state, keys_to_lanes
 from .sketch_estimate import estimate_pallas
 from .sketch_update import add_pallas
-from .sketch_reset import reset_pallas
-from .admission import admit_pallas
-
-LANE = 128
 
 
-def _default_interpret() -> bool:
+def _interpret() -> bool:
+    """Mosaic compiles for the TPU only; elsewhere the kernels interpret."""
     return jax.default_backend() != "tpu"
-
-
-def _pad_lanes(x: jnp.ndarray, mult: int = LANE) -> jnp.ndarray:
-    b = x.shape[0]
-    pad = (-b) % mult
-    if pad:
-        x = jnp.concatenate([x, jnp.zeros((pad,), x.dtype)])
-    return x
 
 
 # ---------------------------------------------------------------------------
@@ -46,50 +38,43 @@ def _pad_lanes(x: jnp.ndarray, mult: int = LANE) -> jnp.ndarray:
 @functools.partial(jax.jit, static_argnums=(0, 4))
 def estimate(cfg: DeviceSketchConfig, state: dict, lo: jnp.ndarray,
              hi: jnp.ndarray, use_pallas: bool = True) -> jnp.ndarray:
-    b = lo.shape[0]
     if not use_pallas:
         return ref.estimate_ref(cfg, state, lo, hi)
-    out = estimate_pallas(cfg, state, _pad_lanes(lo), _pad_lanes(hi),
-                          interpret=_default_interpret())
-    return out[:b]
+    return estimate_pallas(cfg, state, lo, hi, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnums=(0, 4))
 def add(cfg: DeviceSketchConfig, state: dict, lo: jnp.ndarray,
         hi: jnp.ndarray, use_pallas: bool = True) -> dict:
     """Batch add + automatic reset when the sample counter crosses W."""
-    b = lo.shape[0]
     if use_pallas:
-        new = add_pallas(cfg, state, _pad_lanes(lo), _pad_lanes(hi),
-                         n_valid=b, interpret=_default_interpret())
+        new = add_pallas(cfg, state, lo, hi, interpret=_interpret())
     else:
         new = ref.add_ref(cfg, state, lo, hi)
     if cfg.sample_size:
-        def do_reset(s):
-            if use_pallas:
-                return reset_pallas(cfg, s, interpret=_default_interpret())
-            return ref.reset_ref(cfg, s)
-        new = jax.lax.cond(new["size"] >= cfg.sample_size, do_reset,
+        new = jax.lax.cond(new["size"] >= cfg.sample_size,
+                           functools.partial(ref.reset_ref, cfg),
                            lambda s: s, new)
     return new
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
 def reset(cfg: DeviceSketchConfig, state: dict) -> dict:
-    return reset_pallas(cfg, state, interpret=_default_interpret())
+    return ref.reset_ref(cfg, state)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 6))
 def admit(cfg: DeviceSketchConfig, state: dict, cand_lo, cand_hi,
           victim_lo, victim_hi, use_pallas: bool = True) -> jnp.ndarray:
-    b = cand_lo.shape[0]
+    """(B,) bool: admit candidate i over victim i (paper Fig 1)."""
     if not use_pallas:
         return ref.admission_ref(cfg, state, cand_lo, cand_hi,
                                  victim_lo, victim_hi)
-    out = admit_pallas(cfg, state, _pad_lanes(cand_lo), _pad_lanes(cand_hi),
-                       _pad_lanes(victim_lo), _pad_lanes(victim_hi),
-                       interpret=_default_interpret())
-    return out[:b]
+    b = cand_lo.shape[0]
+    est = estimate_pallas(cfg, state, jnp.concatenate([cand_lo, victim_lo]),
+                          jnp.concatenate([cand_hi, victim_hi]),
+                          interpret=_interpret())
+    return est[:b] > est[b:]
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,8 @@
 """Pure-jnp oracles for every Pallas sketch kernel.
 
-These are the semantic ground truth: the kernels in sketch_*.py / admission.py
-must match them bit-for-bit (tests/test_kernels.py sweeps shapes & dtypes).
+These are the semantic ground truth: the kernels in sketch_update.py and
+sketch_estimate.py must match them bit-for-bit (tests/test_kernels.py sweeps
+shapes & dtypes).  ``reset_ref`` is the only reset: it has no kernel.
 They are also directly usable — `jax.jit`-able, differentiable-free integer
 code — wherever interpret-mode Pallas would be slower (CPU serving path).
 """

@@ -749,21 +749,56 @@ def _ldus1(arr, upd, j):
     return jnp.where(iota == j, upd[0], arr)
 
 
-def _ldus_block(tab, blk, s, A):
-    """``dynamic_update_slice(tab, blk, (s*A, 0))`` — whole-set block write.
+def _ldus_block(tab, blk, s):
+    """Whole-set block write into the scan's set-row table layout
+    (:func:`_set_rows`): row ``s`` takes the (A, cols) block ``blk``.
 
-    Takes the SET index ``s`` (not the row offset): the lane form exploits
-    the set alignment to reshape ``tab`` to (n_sets, A, cols) and select the
-    target set with a one-hot broadcast — a generic batched block update
-    (take_along_axis) would instead materialize full-table gathers.
+    Lane mode selects the target row with a one-hot broadcast — a generic
+    batched row update would instead become a scatter.
     """
+    row = blk.reshape(1, -1)
     if not _LANE_TRACE[0]:
-        return jax.lax.dynamic_update_slice(tab, blk, (s * A, 0))
-    n_sets = tab.shape[0] // A
-    t3 = tab.reshape(n_sets, A, tab.shape[1])
-    iota = jnp.arange(n_sets, dtype=jnp.int32)
-    t3 = jnp.where((iota == s)[:, None, None], blk[None, :, :], t3)
-    return t3.reshape(tab.shape)
+        return jax.lax.dynamic_update_slice(tab, row, (s, 0))
+    row = jnp.pad(row, ((0, 0), (0, tab.shape[1] - row.shape[1])))
+    iota = jnp.arange(tab.shape[0], dtype=jnp.int32)
+    return jnp.where((iota == s)[:, None], row, tab)
+
+
+# Inside the access scan every set-associative table is held as SET ROWS:
+# set s is row s of an (n_sets, P) array, its A records packed in the first
+# A*cols words and P rounded up to the 128-lane tile.  The canonical
+# (n_sets*A, cols) record layout tiles badly on the TPU: XLA keeps the
+# carried table column-major (records on lanes, so the few columns are not
+# padded to 128) and then relayouts the WHOLE table on every access to read
+# a set's rows — ~1.5M cycles per access at C=2^20 in the v5e-compiled
+# step.  As set rows, a set read is one dynamic row slice and a set write
+# one row update in whatever layout either backend picks.  step_ref (and
+# the Pallas kernel) convert at entry and exit, so state outside the scan
+# keeps the canonical layout.
+
+def _set_rows(tab: jnp.ndarray, A: int) -> jnp.ndarray:
+    n, cols = tab.shape
+    w = A * cols
+    return jnp.pad(tab.reshape(n // A, w), ((0, 0), (0, -w % 128)))
+
+
+def _set_block(rows: jnp.ndarray, s, A: int, cols: int) -> jnp.ndarray:
+    """Set ``s`` of a set-row table as its (A, cols) record block."""
+    return jax.lax.dynamic_slice(rows, (s, 0), (1, A * cols)).reshape(A, cols)
+
+
+def _scan_tables(spec: StepSpec, state: dict, to_rows: bool) -> dict:
+    """Canonical record tables <-> the scan's set rows (set mode only)."""
+    if spec.assoc is None:
+        return state
+    A = spec.assoc
+
+    def conv(tab, cols):
+        if to_rows:
+            return _set_rows(tab, A)
+        return tab[:, :A * cols].reshape(-1, cols)
+    return {**state, "wtab": conv(state["wtab"], spec.wcols),
+            "mtab": conv(state["mtab"], spec.mcols)}
 
 
 def _counter_vals(spec: StepSpec, words: jnp.ndarray,
@@ -1468,7 +1503,7 @@ def _one_access_set(spec: StepSpec, params: jnp.ndarray, state: dict,
 
     # -- 2. lookups: the key's window set and both main choice sets ----------
     wblk = mask_ways(
-        jax.lax.dynamic_slice(wtab, (kwset * A, 0), (A, spec.wcols)),
+        _set_block(wtab, kwset, A, spec.wcols),
         w_usable(kwset), WT_META)
     wmeta = wblk[:, WT_META]
     match_w = (wblk[:, WT_LO] == klo) & (wblk[:, WT_HI] == khi) & (wmeta >= 0)
@@ -1476,10 +1511,10 @@ def _one_access_set(spec: StepSpec, params: jnp.ndarray, state: dict,
     jw = jnp.argmax(match_w)
 
     mblk1 = mask_ways(
-        jax.lax.dynamic_slice(mtab, (km1 * A, 0), (A, spec.mcols)),
+        _set_block(mtab, km1, A, spec.mcols),
         m_usable(km1), MT_META)
     mblk2 = mask_ways(
-        jax.lax.dynamic_slice(mtab, (km2 * A, 0), (A, spec.mcols)),
+        _set_block(mtab, km2, A, spec.mcols),
         m_usable(km2), MT_META)
 
     def match_in(blk):
@@ -1542,10 +1577,10 @@ def _one_access_set(spec: StepSpec, params: jnp.ndarray, state: dict,
         return jnp.where(c == km2, m2eff, jnp.where(c == km1, mblk1u, cb))
 
     cb1 = fixup(mask_ways(
-        jax.lax.dynamic_slice(mtab, (c1 * A, 0), (A, spec.mcols)),
+        _set_block(mtab, c1, A, spec.mcols),
         m_usable(c1), MT_META), c1)
     cb2 = fixup(mask_ways(
-        jax.lax.dynamic_slice(mtab, (c2 * A, 0), (A, spec.mcols)),
+        _set_block(mtab, c2, A, spec.mcols),
         m_usable(c2), MT_META), c2)
     cblk = jnp.concatenate([cb1, cb2], axis=0)          # (2A, cols)
     # argmin = empty < probation LRU < protected LRU across both sets;
@@ -1580,12 +1615,12 @@ def _one_access_set(spec: StepSpec, params: jnp.ndarray, state: dict,
     cb2u = unmask_ways(cb2u, m_usable(c2), MT_META)
     wblk = unmask_ways(wblk, w_usable(kwset), WT_META)
     zm = _sched_dep(mblk2u) | _sched_dep(cb1u) | _sched_dep(cb2u)
-    mtab = _ldus_block(mtab, mblk1u | zm, km1, A)
-    mtab = _ldus_block(mtab, m2eff, km2, A)
-    mtab = _ldus_block(mtab, cb1u, c1, A)
-    mtab = _ldus_block(mtab, cb2u, c2, A)
-    zw = _sched_dep(cb1u) | _sched_dep(cb2u)    # cand-derived: covers reads
-    wtab = _ldus_block(wtab, wblk | zw, kwset, A)
+    mtab = _ldus_block(mtab, mblk1u | zm, km1)
+    mtab = _ldus_block(mtab, m2eff, km2)
+    mtab = _ldus_block(mtab, cb1u, c1)
+    mtab = _ldus_block(mtab, cb2u, c2)
+    zw = _sched_dep(mtab)       # after every main write: covers all reads
+    wtab = _ldus_block(wtab, wblk | zw, kwset)
 
     # -- 6. bookkeeping (R_PCOUNT is unused: protected counts are per-set) ---
     counted = (hit & (t >= params[P_WARMUP])).astype(jnp.int32)
@@ -1645,13 +1680,13 @@ def _one_access_set_s3fifo(spec: StepSpec, params: jnp.ndarray, state: dict,
     same_km = km2 == km1
 
     # -- lookups: small-FIFO set and both main choice sets -------------------
-    wblk = jax.lax.dynamic_slice(wtab, (kwset * A, 0), (A, spec.wcols))
+    wblk = _set_block(wtab, kwset, A, spec.wcols)
     wmeta = wblk[:, WT_META]
     match_w = (wblk[:, WT_LO] == klo) & (wblk[:, WT_HI] == khi) & (wmeta >= 0)
     hit_w = match_w.any()
 
-    mblk1 = jax.lax.dynamic_slice(mtab, (km1 * A, 0), (A, spec.mcols))
-    mblk2 = jax.lax.dynamic_slice(mtab, (km2 * A, 0), (A, spec.mcols))
+    mblk1 = _set_block(mtab, km1, A, spec.mcols)
+    mblk2 = _set_block(mtab, km2, A, spec.mcols)
 
     def match_in(blk):
         return ((blk[:, MT_LO] == klo) & (blk[:, MT_HI] == khi)
@@ -1688,8 +1723,8 @@ def _one_access_set_s3fifo(spec: StepSpec, params: jnp.ndarray, state: dict,
     def fixup(cb, c):
         return jnp.where(c == km2, m2eff, jnp.where(c == km1, mblk1u, cb))
 
-    cb1 = fixup(jax.lax.dynamic_slice(mtab, (c1 * A, 0), (A, spec.mcols)), c1)
-    cb2 = fixup(jax.lax.dynamic_slice(mtab, (c2 * A, 0), (A, spec.mcols)), c2)
+    cb1 = fixup(_set_block(mtab, c1, A, spec.mcols), c1)
+    cb2 = fixup(_set_block(mtab, c2, A, spec.mcols), c2)
     cblk = jnp.concatenate([cb1, cb2], axis=0)          # (2A, cols)
     tslot = jnp.argmin(cblk[:, MT_META])    # empty < unmarked < marked FIFO
     vic = cblk[tslot]
@@ -1711,12 +1746,12 @@ def _one_access_set_s3fifo(spec: StepSpec, params: jnp.ndarray, state: dict,
 
     # -- writes last (same aliasing/scheduling discipline as wtinylfu) -------
     zm = _sched_dep(mblk2u) | _sched_dep(cb1u) | _sched_dep(cb2u)
-    mtab = _ldus_block(mtab, mblk1u | zm, km1, A)
-    mtab = _ldus_block(mtab, m2eff, km2, A)
-    mtab = _ldus_block(mtab, cb1u, c1, A)
-    mtab = _ldus_block(mtab, cb2u, c2, A)
-    zw = _sched_dep(cb1u) | _sched_dep(cb2u)
-    wtab = _ldus_block(wtab, wblk | zw, kwset, A)
+    mtab = _ldus_block(mtab, mblk1u | zm, km1)
+    mtab = _ldus_block(mtab, m2eff, km2)
+    mtab = _ldus_block(mtab, cb1u, c1)
+    mtab = _ldus_block(mtab, cb2u, c2)
+    zw = _sched_dep(mtab)       # after every main write: covers all reads
+    wtab = _ldus_block(wtab, wblk | zw, kwset)
 
     counted = (hit & (t >= params[P_WARMUP])).astype(jnp.int32)
     regs = jnp.stack([size, regs[R_PCOUNT], t + 1, regs[R_HITS] + counted,
@@ -1762,8 +1797,8 @@ def _one_access_set_arc(spec: StepSpec, params: jnp.ndarray, state: dict,
     mst = t
 
     # -- lookups (all reads first: choice sets + both ghost Bloom halves) ----
-    mblk1 = jax.lax.dynamic_slice(mtab, (km1 * A, 0), (A, spec.mcols))
-    mblk2 = jax.lax.dynamic_slice(mtab, (km2 * A, 0), (A, spec.mcols))
+    mblk1 = _set_block(mtab, km1, A, spec.mcols)
+    mblk2 = _set_block(mtab, km2, A, spec.mcols)
 
     def match_in(blk):
         return ((blk[:, MT_LO] == klo) & (blk[:, MT_HI] == khi)
@@ -1880,8 +1915,8 @@ def _one_access_set_arc(spec: StepSpec, params: jnp.ndarray, state: dict,
 
     # -- writes last ---------------------------------------------------------
     zm = _sched_dep(mb2f)
-    mtab = _ldus_block(mtab, mb1f | zm, km1, A)
-    mtab = _ldus_block(mtab, mb2f, km2, A)
+    mtab = _ldus_block(mtab, mb1f | zm, km1)
+    mtab = _ldus_block(mtab, mb2f, km2)
 
     counted = (hit & (t >= params[P_WARMUP])).astype(jnp.int32)
     regs = jnp.stack([regs[R_SIZE], regs[R_PCOUNT], t + 1,
@@ -1918,8 +1953,8 @@ def _one_access_set_lfu(spec: StepSpec, params: jnp.ndarray, state: dict,
     km1, km2 = kmset[0], kmset[1]
     same_km = km2 == km1
 
-    mblk1 = jax.lax.dynamic_slice(mtab, (km1 * A, 0), (A, spec.mcols))
-    mblk2 = jax.lax.dynamic_slice(mtab, (km2 * A, 0), (A, spec.mcols))
+    mblk1 = _set_block(mtab, km1, A, spec.mcols)
+    mblk2 = _set_block(mtab, km2, A, spec.mcols)
 
     def match_in(blk):
         return ((blk[:, MT_LO] == klo) & (blk[:, MT_HI] == khi)
@@ -1964,8 +1999,8 @@ def _one_access_set_lfu(spec: StepSpec, params: jnp.ndarray, state: dict,
     mb2f = jnp.where(same_km, mb1f, mb2f)
 
     zm = _sched_dep(mb2f)
-    mtab = _ldus_block(mtab, mb1f | zm, km1, A)
-    mtab = _ldus_block(mtab, mb2f, km2, A)
+    mtab = _ldus_block(mtab, mb1f | zm, km1)
+    mtab = _ldus_block(mtab, mb2f, km2)
 
     counted = (hit & (t >= params[P_WARMUP])).astype(jnp.int32)
     regs = jnp.stack([size, regs[R_PCOUNT], t + 1, regs[R_HITS] + counted,
@@ -2219,28 +2254,40 @@ def step_ref(spec: StepSpec, params: jnp.ndarray, state: dict,
     lo = lo.astype(jnp.int32)
     hi = hi.astype(jnp.int32)
     kidx, kdkb, kwset, kmset = precompute_probes(spec, lo, hi)
+    # per-access inputs scan as 1-D columns: a (T, k) input makes the TPU
+    # relayout the whole array on every access to slice one row
+    cols = tuple(kidx.T), tuple(kdkb.T), tuple(kmset.T)
+    state = _scan_tables(spec, state, True)
+
+    def access(carry, klo, khi, ki, kd, kw, km):
+        return _one_access(spec, params, carry, klo, khi, jnp.stack(ki),
+                           jnp.stack(kd), kw, jnp.stack(km))
 
     if n_valid is None:
         # fast path: no tail masking, no per-step state merge
         def body(carry, x):
             klo, khi, ki, kd, kw, km = x
-            return _one_access(spec, params, carry, klo, khi, ki, kd, kw, km)
+            return access(carry, klo, khi, ki, kd, kw, km)
 
-        return jax.lax.scan(body, state, (lo, hi, kidx, kdkb, kwset, kmset),
-                            unroll=unroll)
+        state, hits = jax.lax.scan(
+            body, state, (lo, hi, cols[0], cols[1], kwset, cols[2]),
+            unroll=unroll)
+        return _scan_tables(spec, state, False), hits
 
     n_valid = jnp.asarray(n_valid, jnp.int32)
 
     def body(carry, x):
         klo, khi, ki, kd, kw, km, i = x
-        new, hit = _one_access(spec, params, carry, klo, khi, ki, kd, kw, km)
+        new, hit = access(carry, klo, khi, ki, kd, kw, km)
         active = i < n_valid
         merged = jax.tree_util.tree_map(
             lambda n, o: jnp.where(active, n, o), new, carry)
         return merged, jnp.where(active, hit, 0)
 
-    xs = (lo, hi, kidx, kdkb, kwset, kmset, jnp.arange(b, dtype=jnp.int32))
-    return jax.lax.scan(body, state, xs, unroll=unroll)
+    xs = (lo, hi, cols[0], cols[1], kwset, cols[2],
+          jnp.arange(b, dtype=jnp.int32))
+    state, hits = jax.lax.scan(body, state, xs, unroll=unroll)
+    return _scan_tables(spec, state, False), hits
 
 
 # ---------------------------------------------------------------------------
@@ -2267,20 +2314,20 @@ def _step_kernel(spec: StepSpec, lo_ref, hi_ref, kidx_ref, kdkb_ref,
     kdkb = kdkb_ref[...]
     kwset = kwset_ref[...]
     kmset = kmset_ref[...]
-    state0 = tuple(r[...] for r in in_refs)
+    state0 = _scan_tables(spec, {k: r[...] for k, r in zip(keys, in_refs)},
+                          True)
     hits0 = jnp.zeros(lo.shape, jnp.int32)
 
     def body(i, carry):
-        state_t, hits = carry
-        state = dict(zip(keys, state_t))
+        state, hits = carry
         new, hit = _one_access(spec, params, state, lo[i], hi[i],
                                kidx[i], kdkb[i], kwset[i], kmset[i])
-        return (tuple(new[k] for k in keys),
-                hits.at[i].set(hit))
+        return new, hits.at[i].set(hit)
 
-    state_t, hits = jax.lax.fori_loop(0, n_valid, body, (state0, hits0))
-    for r, v in zip(out_refs, state_t):
-        r[...] = v
+    state, hits = jax.lax.fori_loop(0, n_valid, body, (state0, hits0))
+    state = _scan_tables(spec, state, False)
+    for k, r in zip(keys, out_refs):
+        r[...] = state[k]
     hits_ref[...] = hits
 
 

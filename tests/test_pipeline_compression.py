@@ -22,8 +22,9 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed.pipeline import pipeline_apply
+from repro.distributed.mesh import make_debug_mesh
 
-mesh = jax.make_mesh((4,), ("stage",), devices=jax.devices()[:4])
+mesh = make_debug_mesh((4,), ("stage",))
 L, B, Dm = 8, 8, 16
 key = jax.random.PRNGKey(0)
 ws = jax.random.normal(key, (L, Dm, Dm)) * 0.3
@@ -50,10 +51,10 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.distributed.compression import compressed_allreduce_int8
+from repro.distributed.mesh import make_debug_mesh
 
-mesh = jax.make_mesh((8,), ("data",), devices=jax.devices()[:8])
+mesh = make_debug_mesh((8,), ("data",))
 G = 8
 x = jax.random.normal(jax.random.PRNGKey(0), (G, 64, 32))
 
@@ -61,8 +62,9 @@ def f(xs, err):
     m, e = compressed_allreduce_int8(xs[0], "data", err[0])
     return m[None], e[None]
 
-fn = jax.jit(shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
-                       out_specs=(P("data"), P("data")), check_rep=False))
+fn = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
+                           out_specs=(P("data"), P("data")),
+                           check_vma=False))
 err0 = jnp.zeros_like(x)
 mean, err = fn(x, err0)
 true_mean = x.mean(0)

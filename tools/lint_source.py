@@ -46,8 +46,9 @@ ALLOWED_AT_SCOPES = {
 }
 # S1/S2: whole files outside the fused-scan discipline: the O(capacity)
 # host-reference kernel, the epoch-boundary merge fold, and the pallas
-# batched-admission kernel (Ref indexing, not traced gathers)
-ALLOWED_FILES = {"ref.py", "sketch_merge.py", "sketch_update.py"}
+# serving kernels (Ref indexing, not traced gathers)
+ALLOWED_FILES = {"ref.py", "sketch_merge.py", "sketch_update.py",
+                 "sketch_estimate.py"}
 
 # S2: scopes that may read computed indices directly — each one either
 # implements the width-cliff discipline or carries the _big_operand
