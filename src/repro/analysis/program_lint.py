@@ -32,7 +32,8 @@ Rules (each cross-referenced to the ARCHITECTURE.md symptom table):
         62.8x per-access-psum bug of PR 6, expressed statically.
 ``R7``  byte-identity fingerprints: every "compiles the identical
         program" contract (``policy`` default, ``streams=1``,
-        ``shards=1``, ``adaptive=False``, ``integrity=False``) lowers
+        ``shards=1``, ``adaptive=False``, ``integrity=False``,
+        ``events=False``) lowers
         byte-identical text, and its digest matches the committed
         registry (``fingerprints.json``, keyed by jax version + backend;
         refresh with ``tools/lint_programs.py --update``).
@@ -356,6 +357,7 @@ FINGERPRINT_CONTRACTS = {
     "streams1": {"streams": 1},
     "adaptive-off": {"adaptive": False},
     "integrity-off": {"integrity": False},
+    "events-off": {"events": False},
 }
 
 
@@ -597,6 +599,9 @@ def default_matrix() -> list:
         E("flat-static", lambda: _step_program(dict(capacity=512))),
         E("assoc-static",
           lambda: _step_program(dict(capacity=2048, assoc=8))),
+        E("assoc-events",
+          lambda: _step_program(dict(capacity=2048, assoc=8, events=True)),
+          note="admission event counters in regs"),
         E("assoc-integrity",
           lambda: _sharded_program(
               dict(capacity=2048, assoc=8, shards=4, integrity=True))),

@@ -44,7 +44,8 @@ import jax.numpy as jnp
 from repro.kernels.sketch_step import (StepSpec, MESH_AXIS, make_step_params,
                                        init_step_state, step_ref, step_pallas,
                                        rebalance, _state_keys,
-                                       R_HITS, R_WQUOTA, R_EHITS)
+                                       R_HITS, R_WQUOTA, R_EHITS, R_CANDS,
+                                       R_ADMIT, R_REJECT, R_RESETS)
 from repro.kernels.sketch_common import keys_to_lanes, POLICIES
 from repro.kernels.sketch_merge import merge_halve, merge_halve_mesh
 from . import adaptive
@@ -109,6 +110,14 @@ class DeviceWTinyLFU:
     re-learned by the §3.3 aging within a few sample periods
     (kernels/sketch_merge.py).
 
+    ``events=True`` (set-associative W-TinyLFU only: ``assoc=W``,
+    ``policy="wtinylfu"``, ``shards=1``, ``adaptive=False``) counts the
+    admission events of a run in the step's registers, at no extra
+    transfer: window overflows that pushed a candidate, candidates
+    admitted over a resident victim, candidates rejected by it, and §3.3
+    resets.  Runs return them as ``SimResult.extra["events"]``.  False
+    compiles the identical program.
+
     ``run()`` is the general entry point — it adds epoch-boundary
     checkpointing (``checkpoint_dir=``/``checkpoint_every=``) on top of
     what ``simulate_trace`` does; :func:`resume_trace` restores the latest
@@ -133,6 +142,7 @@ class DeviceWTinyLFU:
     integrity: bool = False       # checksum + shard-quarantine merge fold
     streams: int = 1              # lane-batched tenant caches per program
     policy: str = "wtinylfu"      # device policy panel: s3fifo | arc | lfu
+    events: bool = False          # admission event counters in the regs
 
     def __post_init__(self):
         # eager validation (ISSUE 7): bad values used to surface as XLA
@@ -206,6 +216,12 @@ class DeviceWTinyLFU:
                 raise ValueError(
                     f"policy {self.policy!r} cannot combine with "
                     "integrity=True (it requires shards > 1)")
+        if self.events and (self.assoc is None or self.policy != "wtinylfu"
+                            or self.shards > 1 or self.adaptive):
+            raise ValueError(
+                "events=True counts the set-associative W-TinyLFU step's "
+                "admissions: it requires assoc=W, policy 'wtinylfu', "
+                "shards=1 and adaptive=False")
         if self.policy == "arc" and not self.doorkeeper:
             raise ValueError(
                 "policy 'arc' requires doorkeeper=True: the B1/B2 ghost "
@@ -314,7 +330,7 @@ class DeviceWTinyLFU:
             # normalized so single-device specs share one compile cache key
             mesh_exchange=self.mesh_exchange if self.mesh is not None
             else "chunk", integrity=self.integrity, streams=self.streams,
-            policy=self.policy)
+            policy=self.policy, events=self.events)
 
     @property
     def mesh_devices(self) -> int:
@@ -1097,6 +1113,23 @@ def _row_extra(cfg: "DeviceWTinyLFU", climb: "ClimbSpec | None",
     return extra
 
 
+def _span(phase: str):
+    """Host span ``simulate_trace.<phase>``: recorded on the host plane of
+    a profiler session, on the device ops' clock; ~1 us with no session."""
+    return jax.profiler.TraceAnnotation("simulate_trace." + phase)
+
+
+def _event_counts(regs: np.ndarray) -> dict:
+    """The admission event registers of ``StepSpec.events``, summed over
+    tenant lanes: ``candidates`` (window overflows that pushed one),
+    ``admitted`` and ``rejected`` (a candidate's estimate against a
+    resident victim's: replaced it, or lost), ``resets`` (§3.3)."""
+    regs = regs.reshape(-1, regs.shape[-1])
+    return {name: int(regs[:, r].sum()) for name, r in (
+        ("candidates", R_CANDS), ("admitted", R_ADMIT),
+        ("rejected", R_REJECT), ("resets", R_RESETS))}
+
+
 def simulate_trace(trace: np.ndarray, capacity: int, *,
                    window_frac: float = 0.01, sample_factor: int = 8,
                    warmup: int = 0, backend: str = "jit", chunk: int = 512,
@@ -1123,16 +1156,28 @@ def simulate_trace(trace: np.ndarray, capacity: int, *,
     fused ``merge_halve`` folds the shard deltas into the global estimate
     at every boundary — combined with ``adaptive=True`` the fold rides the
     climb epochs instead.
+
+    ``events=True`` (via cfg_kw; see :class:`DeviceWTinyLFU`) returns the
+    run's admission counts as ``extra["events"]``: ``candidates``,
+    ``admitted``, ``rejected`` and ``resets``, summed over tenant lanes.
+
+    Each host phase runs under a ``jax.profiler.TraceAnnotation`` named
+    ``simulate_trace.<phase>`` (``config``, ``init_state``,
+    ``stage_keys``, ``dispatch``, ``wait``, ``readback``), so a profile
+    of a replay names the host's work on the device ops' clock.
     """
-    cfg = DeviceWTinyLFU(capacity, window_frac=window_frac,
-                         sample_factor=sample_factor, adaptive=adaptive,
-                         **cfg_kw)
-    trace = np.asarray(trace)
-    _check_trace_streams(cfg, trace)
-    spec = cfg.spec()
-    params = cfg.params(warmup=warmup)
-    state = init_step_state(spec, cfg.window_cap, cfg.main_cap)
-    lo, hi = _trace_lanes(trace)
+    with _span("config"):
+        cfg = DeviceWTinyLFU(capacity, window_frac=window_frac,
+                             sample_factor=sample_factor, adaptive=adaptive,
+                             **cfg_kw)
+        trace = np.asarray(trace)
+        _check_trace_streams(cfg, trace)
+        spec = cfg.spec()
+        params = cfg.params(warmup=warmup)
+    with _span("init_state"):
+        state = init_step_state(spec, cfg.window_cap, cfg.main_cap)
+    with _span("stage_keys"):
+        lo, hi = _trace_lanes(trace)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     climb = climb or ClimbSpec()
@@ -1142,59 +1187,66 @@ def simulate_trace(trace: np.ndarray, capacity: int, *,
                          "use backend='jit'")
     t0 = time.perf_counter()
     trajectory = None
-    if adaptive:
-        if backend not in ("jit", "pallas"):
+    with _span("dispatch"):
+        if adaptive:
+            if backend not in ("jit", "pallas"):
+                raise ValueError(f"unknown backend {backend!r}")
+            state, hits, (ehits, quotas), _ = _run_adaptive(
+                cfg, spec, params, state, lo, hi, climb, backend, interpret,
+                mesh=cfg.mesh)
+            if ehits is not None:
+                trajectory = {"epoch_len": climb.epoch_len,
+                              "epoch_hits": np.asarray(ehits).tolist(),
+                              "quota": np.asarray(quotas).tolist()}
+        elif cfg.shards > 1:
+            if backend not in ("jit", "pallas"):
+                raise ValueError(f"unknown backend {backend!r}")
+            state, hits = _run_sharded(spec, params, state, lo, hi,
+                                       cfg.merge_epoch, backend, interpret,
+                                       mesh=cfg.mesh)
+        elif backend == "jit":
+            state, hits = _run_jit(spec, params, state, lo, hi)
+        elif backend == "pallas":
+            state, hits = _run_pallas(spec, params, state, lo, hi, chunk,
+                                      interpret)
+        else:
             raise ValueError(f"unknown backend {backend!r}")
-        state, hits, (ehits, quotas), _ = _run_adaptive(
-            cfg, spec, params, state, lo, hi, climb, backend, interpret,
-            mesh=cfg.mesh)
-        if ehits is not None:
-            trajectory = {"epoch_len": climb.epoch_len,
-                          "epoch_hits": np.asarray(ehits).tolist(),
-                          "quota": np.asarray(quotas).tolist()}
-    elif cfg.shards > 1:
-        if backend not in ("jit", "pallas"):
-            raise ValueError(f"unknown backend {backend!r}")
-        state, hits = _run_sharded(spec, params, state, lo, hi,
-                                   cfg.merge_epoch, backend, interpret,
-                                   mesh=cfg.mesh)
-    elif backend == "jit":
-        state, hits = _run_jit(spec, params, state, lo, hi)
-    elif backend == "pallas":
-        state, hits = _run_pallas(spec, params, state, lo, hi, chunk,
-                                  interpret)
-    else:
-        raise ValueError(f"unknown backend {backend!r}")
-    if cfg.mesh is not None:
-        # hand back the single-device [global || delta] layout so callers
-        # compare final sketch state across placements directly
-        state = _from_mesh_state(spec, state)
-    regs = np.asarray(state["regs"])
-    wall = time.perf_counter() - t0
+        if cfg.mesh is not None:
+            # hand back the single-device [global || delta] layout so callers
+            # compare final sketch state across placements directly
+            state = _from_mesh_state(spec, state)
+    with _span("wait"):
+        jax.block_until_ready(state["regs"])
+    with _span("readback"):
+        regs = np.asarray(state["regs"])
+        wall = time.perf_counter() - t0
 
-    # warmup applies per lane (each tenant's own R_T register counts it)
-    counted = (trace.shape[-1] - warmup) * cfg.streams
-    extra = {"backend": backend, "window_frac": window_frac,
-             "assoc": cfg.assoc, "device": jax.default_backend(),
-             **_row_extra(cfg, climb, adaptive)}
-    if adaptive:
-        extra["adaptive"] = True
-        extra["final_quota"] = ([int(q) for q in regs[:, R_WQUOTA]]
-                                if cfg.streams > 1 else int(regs[R_WQUOTA]))
-        if trajectory is not None:
-            extra["trajectory"] = trajectory
-    if cfg.streams > 1:
-        # aggregate hits in the SimResult; per-lane breakdown in extra
-        # (trajectory rows are already per-lane (ne, B) lists)
-        extra["lane_hits"] = [int(h) for h in regs[:, R_HITS]]
-        n_hits = int(regs[:, R_HITS].sum())
-    else:
-        n_hits = int(regs[R_HITS])
-    res = SimResult(policy=_policy_label(cfg, adaptive),
-                    cache_size=capacity,
-                    trace=trace_name, accesses=counted, hits=n_hits,
-                    hit_ratio=n_hits / max(1, counted),
-                    wall_s=wall, extra=extra)
+        # warmup applies per lane (each tenant's own R_T register counts it)
+        counted = (trace.shape[-1] - warmup) * cfg.streams
+        extra = {"backend": backend, "window_frac": window_frac,
+                 "assoc": cfg.assoc, "device": jax.default_backend(),
+                 **_row_extra(cfg, climb, adaptive)}
+        if adaptive:
+            extra["adaptive"] = True
+            extra["final_quota"] = ([int(q) for q in regs[:, R_WQUOTA]]
+                                    if cfg.streams > 1
+                                    else int(regs[R_WQUOTA]))
+            if trajectory is not None:
+                extra["trajectory"] = trajectory
+        if cfg.streams > 1:
+            # aggregate hits in the SimResult; per-lane breakdown in extra
+            # (trajectory rows are already per-lane (ne, B) lists)
+            extra["lane_hits"] = [int(h) for h in regs[:, R_HITS]]
+            n_hits = int(regs[:, R_HITS].sum())
+        else:
+            n_hits = int(regs[R_HITS])
+        if cfg.events:
+            extra["events"] = _event_counts(regs)
+        res = SimResult(policy=_policy_label(cfg, adaptive),
+                        cache_size=capacity,
+                        trace=trace_name, accesses=counted, hits=n_hits,
+                        hit_ratio=n_hits / max(1, counted),
+                        wall_s=wall, extra=extra)
     if return_state:
         return res, state, hits
     return res
@@ -1259,6 +1311,8 @@ def _config_meta(cfg: "DeviceWTinyLFU", climb: ClimbSpec, warmup: int,
         meta["streams"] = cfg.streams
     if cfg.policy != "wtinylfu":  # absent at default so old manifests match
         meta["policy"] = cfg.policy
+    if cfg.events:               # absent when off: the regs keep 8 slots
+        meta["events"] = True
     if cfg.adaptive:
         meta["climb"] = [int(x) for x in climb.resolve(cfg)]
     meta["warmup"] = int(warmup)
@@ -1409,6 +1463,8 @@ def _run_checkpointed(cfg: "DeviceWTinyLFU", trace, *, warmup=0,
             extra["trajectory"] = {"epoch_len": climb.epoch_len,
                                    "epoch_hits": ehits.tolist(),
                                    "quota": quotas.tolist()}
+    if cfg.events:
+        extra["events"] = _event_counts(regs)
     if checkpoint_dir is not None:
         extra["checkpoint_every"] = every
     if _start:
@@ -1544,6 +1600,10 @@ def simulate_sweep(trace: np.ndarray, capacities, *, window_fracs=(0.01,),
     restricted to one policy may still vmap.  Competitor policies require
     ``assoc=`` (see :class:`DeviceWTinyLFU`).
     """
+    if cfg_kw.get("events"):
+        raise ValueError("events=True counts one run's admissions: use "
+                         "simulate_trace per configuration (sweep rows "
+                         "do not carry the counts)")
     policies = tuple(policies)
     grid = [DeviceWTinyLFU(C, window_frac=wf, sample_factor=sample_factor,
                            adaptive=adaptive, policy=pol, **cfg_kw)

@@ -143,6 +143,13 @@ R_WCOUNT = 5                  # resident window entries (flat adaptive only)
 R_MCOUNT = 6                  # resident main entries (flat adaptive only)
 R_EHITS = 7                   # hits this epoch (reset by rebalance)
 NREGS = 8
+# admission event counters, present only with StepSpec.events (regs grows
+# to NREGS_EVENTS slots; without the flag the program is unchanged)
+R_CANDS = 8                   # window overflows that pushed a candidate
+R_ADMIT = 9                   # candidates that beat the victim, replaced it
+R_REJECT = 10                 # candidates that lost to the victim
+R_RESETS = 11                 # accesses on which the §3.3 reset fired
+NREGS_EVENTS = 12
 
 # packed set-associative record columns (window carries two extra lanes: the
 # resident key's two candidate main-table set indices, so a displaced
@@ -285,6 +292,15 @@ class StepSpec:
         is QUARANTINED — its global and delta slices are zeroed — and the
         paper's §3.3 aging re-learns its counts within a few sample
         periods.  False compiles the identical program.
+    ``events`` (default False)
+        Admission event counters (set-associative W-TinyLFU path only):
+        ``regs`` grows from ``NREGS`` to ``NREGS_EVENTS`` slots, and step 6
+        of :func:`_one_access_set` adds per access into ``R_CANDS`` (a
+        window overflow pushed a candidate), ``R_ADMIT`` (the candidate
+        beat a resident victim's estimate and replaced it), ``R_REJECT``
+        (it lost) and ``R_RESETS`` (the §3.3 reset fired).  An insert into
+        a free slot counts only as a candidate.  False compiles the
+        identical program.
     """
     width: int                    # sketch counters per row (pow2, mult of 8)
     rows: int = 4
@@ -301,6 +317,7 @@ class StepSpec:
     integrity: bool = False       # per-shard checksums + quarantine fold
     streams: int = 1              # lane-batched tenant instances (B >= 1)
     policy: str = "wtinylfu"      # admission/victim rules (POLICIES enum)
+    events: bool = False          # admission event counters in regs
 
     def __post_init__(self):
         assert self.policy in POLICIES, (
@@ -328,6 +345,12 @@ class StepSpec:
                 "streams (lane-batched tenants) cannot combine with "
                 "mesh_devices (the lanes would vmap over the mesh axis "
                 "the shard_map already owns)")
+        if self.events:
+            assert (self.assoc is not None and self.policy == "wtinylfu"
+                    and self.shards == 1 and not self.adaptive), (
+                "events counts the set-associative W-TinyLFU path's "
+                "admissions (assoc=W, policy 'wtinylfu', shards=1, "
+                "adaptive=False)")
         if self.integrity:
             assert self.shards > 1, (
                 "integrity checksums cover the per-shard global sketch "
@@ -492,7 +515,7 @@ def init_step_state(spec: StepSpec, window_cap: int | None = None,
     mcap = spec.main_slots if main_cap is None else int(main_cap)
     assert 1 <= wcap <= spec.window_slots and 1 <= mcap <= spec.main_slots
 
-    regs = jnp.zeros((NREGS,), jnp.int32)
+    regs = jnp.zeros((NREGS_EVENTS if spec.events else NREGS,), jnp.int32)
     if spec.adaptive:
         regs = regs.at[R_WQUOTA].set(wcap)
     # sharded (sketch_halves == 2): the arrays carry [global || delta]
@@ -952,41 +975,44 @@ def _sketch_add(spec: StepSpec, params, counters, dk, size, kidx, kdkb,
         # sharded: aging is deferred to the epoch-boundary merge_halve fold
         # (kernels/sketch_merge.py) — the per-access path never resets
         return counters, dk, size
-    do_reset = (params[P_SAMPLE] > 0) & (size >= params[P_SAMPLE])
-    # lanes: the dynamic-trip-count word loops would batch into a masked
-    # while over PER-LANE trip counts with one scatter per word — the fused
-    # masked pass (identical arithmetic) is the scatter-free form, and the
-    # small per-tenant sketches of a lane-batched run sit well below the
-    # size where the masked pass was ever a problem
-    if use_cond and not _LANE_TRACE[0]:
-        # dynamic-trip-count word loops: 0 iterations on the (vast majority
-        # of) accesses where no reset fires, in-place single-word updates
-        # when it does.  Neither lax.cond (copies its big operands on every
-        # call) nor a masked where (a full O(width) pass every access) keeps
-        # the set path's per-access cost capacity-independent on XLA CPU.
-        def halve_one(i, c):
-            w = jax.lax.dynamic_slice(c, (i,), (1,))
-            return jax.lax.dynamic_update_slice(
-                c, halve_words(w, spec.counter_bits), (i,))
+    with jax.named_scope("reset"):
+        do_reset = (params[P_SAMPLE] > 0) & (size >= params[P_SAMPLE])
+        # lanes: the dynamic-trip-count word loops would batch into a
+        # masked while over PER-LANE trip counts with one scatter per word —
+        # the fused masked pass (identical arithmetic) is the scatter-free
+        # form, and the small per-tenant sketches of a lane-batched run sit
+        # well below the size where the masked pass was ever a problem
+        if use_cond and not _LANE_TRACE[0]:
+            # dynamic-trip-count word loops: 0 iterations on the (vast
+            # majority of) accesses where no reset fires, in-place
+            # single-word updates when it does.  Neither lax.cond (copies
+            # its big operands on every call) nor a masked where (a full
+            # O(width) pass every access) keeps the set path's per-access
+            # cost capacity-independent on XLA CPU.
+            def halve_one(i, c):
+                w = jax.lax.dynamic_slice(c, (i,), (1,))
+                return jax.lax.dynamic_update_slice(
+                    c, halve_words(w, spec.counter_bits), (i,))
 
-        def zero_one(i, d):
-            return jax.lax.dynamic_update_slice(
-                d, jnp.zeros((1,), jnp.int32), (i,))
+            def zero_one(i, d):
+                return jax.lax.dynamic_update_slice(
+                    d, jnp.zeros((1,), jnp.int32), (i,))
 
-        counters = jax.lax.fori_loop(
-            0, jnp.where(do_reset, counters.shape[0], 0), halve_one, counters)
-        dk = jax.lax.fori_loop(
-            0, jnp.where(do_reset, dk.shape[0], 0), zero_one, dk)
-        size = jnp.where(do_reset, size // 2, size)
-    else:
-        # select, not lax.cond: XLA CPU cond copies its operand buffers every
-        # step, which costs more than the fused masked pass it would skip at
-        # the flat path's small sketch sizes
-        counters = jnp.where(do_reset,
-                             halve_words(counters, spec.counter_bits),
-                             counters)
-        dk = jnp.where(do_reset, jnp.zeros_like(dk), dk)
-        size = jnp.where(do_reset, size // 2, size)
+            counters = jax.lax.fori_loop(
+                0, jnp.where(do_reset, counters.shape[0], 0), halve_one,
+                counters)
+            dk = jax.lax.fori_loop(
+                0, jnp.where(do_reset, dk.shape[0], 0), zero_one, dk)
+            size = jnp.where(do_reset, size // 2, size)
+        else:
+            # select, not lax.cond: XLA CPU cond copies its operand buffers
+            # every step, which costs more than the fused masked pass it
+            # would skip at the flat path's small sketch sizes
+            counters = jnp.where(do_reset,
+                                 halve_words(counters, spec.counter_bits),
+                                 counters)
+            dk = jnp.where(do_reset, jnp.zeros_like(dk), dk)
+            size = jnp.where(do_reset, size // 2, size)
     return counters, dk, size
 
 
@@ -1439,13 +1465,14 @@ def _one_access_set(spec: StepSpec, params: jnp.ndarray, state: dict,
     # (sharded: the add writes the delta half only; no per-access reset —
     # aging happens in the epoch-boundary merge_halve fold; mesh:
     # global/local-delta pairs)
-    if spec.mesh_devices:
-        cin = (state["counters"], state["dcounters"])
-        din = (state["doorkeeper"], state["ddoorkeeper"])
-    else:
-        cin, din = state["counters"], state["doorkeeper"]
-    counters, dk, size = _sketch_add(spec, params, cin, din, regs[R_SIZE],
-                                     kidx, kdkb, use_cond=True)
+    with jax.named_scope("sketch"):
+        if spec.mesh_devices:
+            cin = (state["counters"], state["dcounters"])
+            din = (state["doorkeeper"], state["ddoorkeeper"])
+        else:
+            cin, din = state["counters"], state["doorkeeper"]
+        counters, dk, size = _sketch_add(spec, params, cin, din, regs[R_SIZE],
+                                         kidx, kdkb, use_cond=True)
 
     wtab, mtab = state["wtab"], state["mtab"]
     km1, km2 = kmset[0], kmset[1]
@@ -1502,140 +1529,162 @@ def _one_access_set(spec: StepSpec, params: jnp.ndarray, state: dict,
         mst = t
 
     # -- 2. lookups: the key's window set and both main choice sets ----------
-    wblk = mask_ways(
-        _set_block(wtab, kwset, A, spec.wcols),
-        w_usable(kwset), WT_META)
-    wmeta = wblk[:, WT_META]
-    match_w = (wblk[:, WT_LO] == klo) & (wblk[:, WT_HI] == khi) & (wmeta >= 0)
-    hit_w = match_w.any()
-    jw = jnp.argmax(match_w)
+    with jax.named_scope("lookup"):
+        wblk = mask_ways(
+            _set_block(wtab, kwset, A, spec.wcols),
+            w_usable(kwset), WT_META)
+        wmeta = wblk[:, WT_META]
+        match_w = ((wblk[:, WT_LO] == klo) & (wblk[:, WT_HI] == khi)
+                   & (wmeta >= 0))
+        hit_w = match_w.any()
+        jw = jnp.argmax(match_w)
 
-    mblk1 = mask_ways(
-        _set_block(mtab, km1, A, spec.mcols),
-        m_usable(km1), MT_META)
-    mblk2 = mask_ways(
-        _set_block(mtab, km2, A, spec.mcols),
-        m_usable(km2), MT_META)
+        mblk1 = mask_ways(
+            _set_block(mtab, km1, A, spec.mcols),
+            m_usable(km1), MT_META)
+        mblk2 = mask_ways(
+            _set_block(mtab, km2, A, spec.mcols),
+            m_usable(km2), MT_META)
 
-    def match_in(blk):
-        return ((blk[:, MT_LO] == klo) & (blk[:, MT_HI] == khi)
-                & (blk[:, MT_META] >= 0))
+        def match_in(blk):
+            return ((blk[:, MT_LO] == klo) & (blk[:, MT_HI] == khi)
+                    & (blk[:, MT_META] >= 0))
 
-    match1 = match_in(mblk1)
-    match2 = match_in(mblk2) & ~same_km     # aliased choices: count set1 only
-    hit1 = match1.any()
-    hit2 = match2.any()
-    hit_m = hit1 | hit2
-    hit = hit_w | hit_m
+        match1 = match_in(mblk1)
+        match2 = match_in(mblk2) & ~same_km  # aliased choices: set1 only
+        hit1 = match1.any()
+        hit2 = match2.any()
+        hit_m = hit1 | hit2
+        hit = hit_w | hit_m
 
     # -- 3a. window hit/miss: refresh stamp, insert on miss (not yet written)
-    wmeta = _lset(wmeta, jw, wst, hit_w)
-    miss = ~hit
-    ws = jnp.argmin(wmeta)
-    newrow = jnp.concatenate(
-        [jnp.stack([klo, khi, wst, km1, km2]), kidx, kdkb]).astype(jnp.int32)
-    # padding (+MAX) can only win the argmin in a zero-way set (vmapped
-    # sweeps far below the shared geometry, or degenerate tiny windows):
-    # such an access bypasses the window — the incoming key itself becomes
-    # the admission candidate, exactly like the host twin's insert-then-
-    # immediately-displace
-    w_ok = wmeta[ws] != _I32_MAX
-    push = miss & ((wmeta[ws] >= 0) | ~w_ok)
-    cand = jnp.where(w_ok, wblk[ws], newrow)    # full packed record
-    wblk = _lset_col(wblk, WT_META, wmeta)
-    wblk = _lset_row(wblk, ws, newrow, miss & w_ok)
+    with jax.named_scope("window"):
+        wmeta = _lset(wmeta, jw, wst, hit_w)
+        miss = ~hit
+        ws = jnp.argmin(wmeta)
+        newrow = jnp.concatenate(
+            [jnp.stack([klo, khi, wst, km1, km2]), kidx, kdkb]
+        ).astype(jnp.int32)
+        # padding (+MAX) can only win the argmin in a zero-way set (vmapped
+        # sweeps far below the shared geometry, or degenerate tiny windows):
+        # such an access bypasses the window — the incoming key itself
+        # becomes the admission candidate, exactly like the host twin's
+        # insert-then-immediately-displace
+        w_ok = wmeta[ws] != _I32_MAX
+        push = miss & ((wmeta[ws] >= 0) | ~w_ok)
+        cand = jnp.where(w_ok, wblk[ws], newrow)    # full packed record
+        wblk = _lset_col(wblk, WT_META, wmeta)
+        wblk = _lset_row(wblk, ws, newrow, miss & w_ok)
 
     # -- 3b. main hit: SLRU promote-or-refresh within the RESIDENT set -------
-    def hit_update(blk, match, hit_half):
-        meta = blk[:, MT_META]
-        j = jnp.argmax(match)
-        meta = _lset(meta, j, _PROT | mst, hit_half)
-        # the set's protected budget scales its usable ways by the global
-        # protected fraction; counting resident protected beats carrying a
-        # per-set register (padding meta +MAX excluded: stamps < 2^31-1)
-        usable = (meta != _I32_MAX).sum()
-        nprot = ((meta >= _PROT) & (meta != _I32_MAX)).sum()
-        cap = jnp.maximum(1, usable * params[P_PROT_CAP]
-                          // jnp.maximum(1, params[P_MAIN_CAP]))
-        over = hit_half & (nprot > cap)
-        kd = jnp.argmin(jnp.where(meta >= _PROT, meta, _I32_MAX))
-        meta = _lset(meta, kd, mst, over)
-        return _lset_col(blk, MT_META, meta)
+    with jax.named_scope("slru"):
+        def hit_update(blk, match, hit_half):
+            meta = blk[:, MT_META]
+            j = jnp.argmax(match)
+            meta = _lset(meta, j, _PROT | mst, hit_half)
+            # the set's protected budget scales its usable ways by the global
+            # protected fraction; counting resident protected beats carrying a
+            # per-set register (padding meta +MAX excluded: stamps < 2^31-1)
+            usable = (meta != _I32_MAX).sum()
+            nprot = ((meta >= _PROT) & (meta != _I32_MAX)).sum()
+            cap = jnp.maximum(1, usable * params[P_PROT_CAP]
+                              // jnp.maximum(1, params[P_MAIN_CAP]))
+            over = hit_half & (nprot > cap)
+            kd = jnp.argmin(jnp.where(meta >= _PROT, meta, _I32_MAX))
+            meta = _lset(meta, kd, mst, over)
+            return _lset_col(blk, MT_META, meta)
 
-    mblk1u = hit_update(mblk1, match1, hit1)
-    mblk2u = hit_update(mblk2, match2, hit2)
-    m2eff = jnp.where(same_km, mblk1u, mblk2u)  # aliased sets follow set1
+        mblk1u = hit_update(mblk1, match1, hit1)
+        mblk2u = hit_update(mblk2, match2, hit2)
+        m2eff = jnp.where(same_km, mblk1u, mblk2u)  # aliased sets follow set1
 
     # -- 4. admission: candidate vs the weakest of its 2*ways records --------
     # the candidate's choice sets were stored at its window insert; they are
     # gathered from the PRE-access table, then the hit updates above are
     # replayed onto them wherever the sets alias
-    c1, c2 = cand[WT_MSET], cand[WT_MSET2]
-    same_c = c2 == c1
+    with jax.named_scope("admission"):
+        c1, c2 = cand[WT_MSET], cand[WT_MSET2]
+        same_c = c2 == c1
 
-    def fixup(cb, c):
-        return jnp.where(c == km2, m2eff, jnp.where(c == km1, mblk1u, cb))
+        def fixup(cb, c):
+            return jnp.where(c == km2, m2eff, jnp.where(c == km1, mblk1u, cb))
 
-    cb1 = fixup(mask_ways(
-        _set_block(mtab, c1, A, spec.mcols),
-        m_usable(c1), MT_META), c1)
-    cb2 = fixup(mask_ways(
-        _set_block(mtab, c2, A, spec.mcols),
-        m_usable(c2), MT_META), c2)
-    cblk = jnp.concatenate([cb1, cb2], axis=0)          # (2A, cols)
-    # argmin = empty < probation LRU < protected LRU across both sets;
-    # ties pick the first half, so aliased choice sets stay consistent
-    tslot = jnp.argmin(cblk[:, MT_META])
-    vic = cblk[tslot]
-    m_free = vic[MT_META] < 0
-    est = _estimate_pair(
-        spec, counters, dk,
-        jnp.stack([cand[5:5 + rows], vic[3:3 + rows]]),
-        jnp.stack([cand[5 + rows:5 + rows + dkp], vic[3 + rows:3 + rows + dkp]]))
-    admit = est[0] > est[1]
-    # all-padding candidate sets (see w_ok above) never accept an insert
-    do_ins = push & (vic[MT_META] != _I32_MAX) & (m_free | admit)
-    candrow = jnp.concatenate(
-        [jnp.stack([cand[WT_LO], cand[WT_HI], mst]),
-         cand[5:5 + rows], cand[5 + rows:5 + rows + dkp]]).astype(jnp.int32)
-    in1 = do_ins & (tslot < A)
-    in2 = do_ins & (tslot >= A)
-    j1 = jnp.minimum(tslot, A - 1)
-    j2 = jnp.clip(tslot - A, 0, A - 1)
-    cb1u = _lset_row(cb1, j1, candrow, in1)
-    cb2u = _lset_row(cb2, j2, candrow, in2)
-    cb2u = jnp.where(same_c, cb1u, cb2u)
+        cb1 = fixup(mask_ways(
+            _set_block(mtab, c1, A, spec.mcols),
+            m_usable(c1), MT_META), c1)
+        cb2 = fixup(mask_ways(
+            _set_block(mtab, c2, A, spec.mcols),
+            m_usable(c2), MT_META), c2)
+        cblk = jnp.concatenate([cb1, cb2], axis=0)          # (2A, cols)
+        # argmin = empty < probation LRU < protected LRU across both sets;
+        # ties pick the first half, so aliased choice sets stay consistent
+        tslot = jnp.argmin(cblk[:, MT_META])
+        vic = cblk[tslot]
+        m_free = vic[MT_META] < 0
+        est = _estimate_pair(
+            spec, counters, dk,
+            jnp.stack([cand[5:5 + rows], vic[3:3 + rows]]),
+            jnp.stack([cand[5 + rows:5 + rows + dkp],
+                       vic[3 + rows:3 + rows + dkp]]))
+        admit = est[0] > est[1]
+        # all-padding candidate sets (see w_ok above) never accept an insert
+        do_ins = push & (vic[MT_META] != _I32_MAX) & (m_free | admit)
+        candrow = jnp.concatenate(
+            [jnp.stack([cand[WT_LO], cand[WT_HI], mst]),
+             cand[5:5 + rows], cand[5 + rows:5 + rows + dkp]]
+        ).astype(jnp.int32)
+        in1 = do_ins & (tslot < A)
+        in2 = do_ins & (tslot >= A)
+        j1 = jnp.minimum(tslot, A - 1)
+        j2 = jnp.clip(tslot - A, 0, A - 1)
+        cb1u = _lset_row(cb1, j1, candrow, in1)
+        cb2u = _lset_row(cb2, j2, candrow, in2)
+        cb2u = jnp.where(same_c, cb1u, cb2u)
 
     # -- 5. writes last; later writes win where the four sets alias ----------
     # (adaptive: masked ways are restored to EMPTY before the write — the
     # decisions above never touched them, and storage must stay quota-free)
-    mblk1u = unmask_ways(mblk1u, m_usable(km1), MT_META)
-    m2eff = unmask_ways(m2eff, m_usable(km2), MT_META)
-    cb1u = unmask_ways(cb1u, m_usable(c1), MT_META)
-    cb2u = unmask_ways(cb2u, m_usable(c2), MT_META)
-    wblk = unmask_ways(wblk, w_usable(kwset), WT_META)
-    zm = _sched_dep(mblk2u) | _sched_dep(cb1u) | _sched_dep(cb2u)
-    mtab = _ldus_block(mtab, mblk1u | zm, km1)
-    mtab = _ldus_block(mtab, m2eff, km2)
-    mtab = _ldus_block(mtab, cb1u, c1)
-    mtab = _ldus_block(mtab, cb2u, c2)
-    zw = _sched_dep(mtab)       # after every main write: covers all reads
-    wtab = _ldus_block(wtab, wblk | zw, kwset)
+    with jax.named_scope("writes"):
+        mblk1u = unmask_ways(mblk1u, m_usable(km1), MT_META)
+        m2eff = unmask_ways(m2eff, m_usable(km2), MT_META)
+        cb1u = unmask_ways(cb1u, m_usable(c1), MT_META)
+        cb2u = unmask_ways(cb2u, m_usable(c2), MT_META)
+        wblk = unmask_ways(wblk, w_usable(kwset), WT_META)
+        zm = _sched_dep(mblk2u) | _sched_dep(cb1u) | _sched_dep(cb2u)
+        mtab = _ldus_block(mtab, mblk1u | zm, km1)
+        mtab = _ldus_block(mtab, m2eff, km2)
+        mtab = _ldus_block(mtab, cb1u, c1)
+        mtab = _ldus_block(mtab, cb2u, c2)
+        zw = _sched_dep(mtab)       # after every main write: covers all reads
+        wtab = _ldus_block(wtab, wblk | zw, kwset)
 
     # -- 6. bookkeeping (R_PCOUNT is unused: protected counts are per-set) ---
-    counted = (hit & (t >= params[P_WARMUP])).astype(jnp.int32)
-    if spec.adaptive:
-        # per-set window-traffic telemetry feeding the next rebalance's
-        # load-aware quota distribution (single-word DUS, O(1) per access)
-        wsl = state["wsl"]
-        lcur = jax.lax.dynamic_slice(wsl, (kwset,), (1,))
-        wsl = _ldus1(wsl, lcur + 1, kwset)
-        regs = jnp.stack([size, regs[R_PCOUNT], t + 1, regs[R_HITS] + counted,
-                          wquota, regs[5], regs[6],
-                          regs[R_EHITS] + hit.astype(jnp.int32)])
-    else:
-        regs = jnp.stack([size, regs[R_PCOUNT], t + 1, regs[R_HITS] + counted,
-                          regs[4], regs[5], regs[6], regs[7]])
+    with jax.named_scope("bookkeeping"):
+        counted = (hit & (t >= params[P_WARMUP])).astype(jnp.int32)
+        if spec.adaptive:
+            # per-set window-traffic telemetry feeding the next rebalance's
+            # load-aware quota distribution (single-word DUS, O(1) per access)
+            wsl = state["wsl"]
+            lcur = jax.lax.dynamic_slice(wsl, (kwset,), (1,))
+            wsl = _ldus1(wsl, lcur + 1, kwset)
+            new_regs = [size, regs[R_PCOUNT], t + 1, regs[R_HITS] + counted,
+                        wquota, regs[5], regs[6],
+                        regs[R_EHITS] + hit.astype(jnp.int32)]
+        else:
+            new_regs = [size, regs[R_PCOUNT], t + 1, regs[R_HITS] + counted,
+                        regs[4], regs[5], regs[6], regs[7]]
+        if spec.events:
+            # a candidate met a resident victim: the estimate decided it
+            contest = push & (vic[MT_META] != _I32_MAX) & ~m_free
+            # one vector add for the four counters: scalar adds per counter
+            # cost the TPU's scalar unit more than one small fusion; the
+            # reset halves the count, so size is no longer size + 1
+            inc = jnp.stack([push, contest & admit, contest & ~admit,
+                             size != regs[R_SIZE] + 1]).astype(jnp.int32)
+            regs = jnp.concatenate([jnp.stack(new_regs),
+                                    regs[R_CANDS:NREGS_EVENTS] + inc])
+        else:
+            regs = jnp.stack(new_regs)
     if spec.mesh_devices:
         (cg, cd), (dkg, dd) = counters, dk
         sketch = {"counters": cg, "doorkeeper": dkg,
@@ -2253,11 +2302,13 @@ def step_ref(spec: StepSpec, params: jnp.ndarray, state: dict,
     (b,) = lo.shape
     lo = lo.astype(jnp.int32)
     hi = hi.astype(jnp.int32)
-    kidx, kdkb, kwset, kmset = precompute_probes(spec, lo, hi)
+    with jax.named_scope("probes"):
+        kidx, kdkb, kwset, kmset = precompute_probes(spec, lo, hi)
     # per-access inputs scan as 1-D columns: a (T, k) input makes the TPU
     # relayout the whole array on every access to slice one row
     cols = tuple(kidx.T), tuple(kdkb.T), tuple(kmset.T)
-    state = _scan_tables(spec, state, True)
+    with jax.named_scope("layout"):
+        state = _scan_tables(spec, state, True)
 
     def access(carry, klo, khi, ki, kd, kw, km):
         return _one_access(spec, params, carry, klo, khi, jnp.stack(ki),
@@ -2272,7 +2323,8 @@ def step_ref(spec: StepSpec, params: jnp.ndarray, state: dict,
         state, hits = jax.lax.scan(
             body, state, (lo, hi, cols[0], cols[1], kwset, cols[2]),
             unroll=unroll)
-        return _scan_tables(spec, state, False), hits
+        with jax.named_scope("layout"):
+            return _scan_tables(spec, state, False), hits
 
     n_valid = jnp.asarray(n_valid, jnp.int32)
 
@@ -2287,7 +2339,8 @@ def step_ref(spec: StepSpec, params: jnp.ndarray, state: dict,
     xs = (lo, hi, cols[0], cols[1], kwset, cols[2],
           jnp.arange(b, dtype=jnp.int32))
     state, hits = jax.lax.scan(body, state, xs, unroll=unroll)
-    return _scan_tables(spec, state, False), hits
+    with jax.named_scope("layout"):
+        return _scan_tables(spec, state, False), hits
 
 
 # ---------------------------------------------------------------------------
