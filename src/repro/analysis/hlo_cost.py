@@ -66,9 +66,11 @@ _WRITE_ONLY = {"iota", "broadcast"}
 
 _SHAPE_TOKEN = re.compile(r"([a-z][a-z0-9]*)\[([\d,]*)\]")
 _COMMENT = re.compile(r"/\*.*?\*/")
-# name = <type> kind(args...   — type is either a (tuple, ...) or one token
+# name = <type> kind(args...   — type is either a (tuple, ...) or one token;
+# a TPU layout in a tuple nests one level of parens: s32[8]{0:T(128)}
 _OP_LINE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?([\w\.\-]+)\s*=\s*(\([^)]*\)|\S+)\s+([\w\-]+)\((.*)$")
+    r"^\s*(?:ROOT\s+)?%?([\w\.\-]+)\s*=\s*(\((?:[^()]|\([^()]*\))*\)|\S+)"
+    r"\s+([\w\-]+)\((.*)$")
 _CALLED = re.compile(
     r"(?:condition|body|calls|to_apply|true_computation|"
     r"false_computation|branch_computations)=\{?%?([\w\.\-]+)")

@@ -651,6 +651,47 @@ def precompute_probes(spec: StepSpec, lo: jnp.ndarray, hi: jnp.ndarray):
 
 
 # ---------------------------------------------------------------------------
+# packed access records — the one way the set path makes a table record
+# ---------------------------------------------------------------------------
+
+# accesses per block of packed records: ``step_ref`` builds a block's
+# records when its scan reaches the block, so their buffer stays bounded
+# whatever the trace length.  On the TPU a record's lanes pad to 128, 512 B
+# an access: a whole-trace buffer would fill 16 GB at ~31M accesses, and a
+# block is 2 MB a stream (G times that for a vmapped sweep of G configs).
+_RECORD_BLOCK = 1 << 12
+
+
+def access_records(lo, hi, kidx, kdkb, kmset) -> jnp.ndarray:
+    """Window records ``[lo, hi, 0, mset1, mset2, idx[rows], dkb[dkp]]`` of
+    accesses, along the last axis, with the meta lane zero.
+
+    ``step_ref`` builds them a block of :data:`_RECORD_BLOCK` accesses at a
+    time, outside the access scan, and scans the ``(K, wcols)`` block as
+    one more input: each access then reads its record as one row slice,
+    where assembling it in the scan body from scalars costs a
+    scalar-to-vector move per lane, twice an access on the TPU.  A
+    lane-batched run (:data:`_LANE_TRACE`) builds each access's record in
+    the body from its ``(B,)`` lane vectors instead.
+    """
+    z = jnp.zeros_like(lo)
+    return jnp.concatenate(
+        [jnp.stack([lo, hi, z, kmset[..., 0], kmset[..., 1]], axis=-1),
+         kidx, kdkb], axis=-1).astype(jnp.int32)
+
+
+def _with_meta(rec: jnp.ndarray, meta) -> jnp.ndarray:
+    """``rec`` with its meta lane (``WT_META == MT_META``) set: one select."""
+    lane = jnp.arange(rec.shape[-1], dtype=jnp.int32)
+    return jnp.where(lane == WT_META, meta, rec)
+
+
+def _main_record(rec: jnp.ndarray) -> jnp.ndarray:
+    """The main-table record of a window record: its two set lanes dropped."""
+    return jnp.concatenate([rec[:WT_META + 1], rec[WT_MSET2 + 1:]])
+
+
+# ---------------------------------------------------------------------------
 # functional single-access step — the one source of truth for both backends
 # ---------------------------------------------------------------------------
 
@@ -1441,9 +1482,10 @@ def _sched_dep(x: jnp.ndarray) -> jnp.ndarray:
 
 
 def _one_access_set(spec: StepSpec, params: jnp.ndarray, state: dict,
-                    klo, khi, kidx, kdkb, kwset, kmset):
+                    klo, khi, kidx, kdkb, kwset, kmset, krec):
     """One access against W-way set-associative tables: every table touch is
     a contiguous (assoc, cols) gather + reduce — O(ways), capacity-free.
+    ``krec`` is the access's packed window record (:func:`access_records`).
 
     The main table uses power-of-two-choices placement: a key may reside in
     either of its two hashed sets (lookups probe both); a displaced window
@@ -1562,9 +1604,7 @@ def _one_access_set(spec: StepSpec, params: jnp.ndarray, state: dict,
         wmeta = _lset(wmeta, jw, wst, hit_w)
         miss = ~hit
         ws = jnp.argmin(wmeta)
-        newrow = jnp.concatenate(
-            [jnp.stack([klo, khi, wst, km1, km2]), kidx, kdkb]
-        ).astype(jnp.int32)
+        newrow = _with_meta(krec, wst)
         # padding (+MAX) can only win the argmin in a zero-way set (vmapped
         # sweeps far below the shared geometry, or degenerate tiny windows):
         # such an access bypasses the window — the incoming key itself
@@ -1629,10 +1669,7 @@ def _one_access_set(spec: StepSpec, params: jnp.ndarray, state: dict,
         admit = est[0] > est[1]
         # all-padding candidate sets (see w_ok above) never accept an insert
         do_ins = push & (vic[MT_META] != _I32_MAX) & (m_free | admit)
-        candrow = jnp.concatenate(
-            [jnp.stack([cand[WT_LO], cand[WT_HI], mst]),
-             cand[5:5 + rows], cand[5 + rows:5 + rows + dkp]]
-        ).astype(jnp.int32)
+        candrow = _main_record(_with_meta(cand, mst))
         in1 = do_ins & (tslot < A)
         in2 = do_ins & (tslot >= A)
         j1 = jnp.minimum(tslot, A - 1)
@@ -1699,7 +1736,7 @@ def _one_access_set(spec: StepSpec, params: jnp.ndarray, state: dict,
 
 
 def _one_access_set_s3fifo(spec: StepSpec, params: jnp.ndarray, state: dict,
-                           klo, khi, kidx, kdkb, kwset, kmset):
+                           klo, khi, kidx, kdkb, kwset, kmset, krec):
     """One access under the ``"s3fifo"`` competitor policy.
 
     S3-FIFO (SNIPPETS.md / CacheKit competitor set) on the shared
@@ -1748,8 +1785,7 @@ def _one_access_set_s3fifo(spec: StepSpec, params: jnp.ndarray, state: dict,
     # -- small-FIFO miss insert (hit: NO write — FIFO order is insert order) -
     miss = ~hit
     ws = jnp.argmin(wmeta)                  # oldest insert stamp (or empty)
-    newrow = jnp.concatenate(
-        [jnp.stack([klo, khi, t, km1, km2]), kidx, kdkb]).astype(jnp.int32)
+    newrow = _with_meta(krec, t)
     w_ok = wmeta[ws] != _I32_MAX            # zero-way window set: bypass
     push = miss & ((wmeta[ws] >= 0) | ~w_ok)
     cand = jnp.where(w_ok, wblk[ws], newrow)
@@ -1782,9 +1818,7 @@ def _one_access_set_s3fifo(spec: StepSpec, params: jnp.ndarray, state: dict,
                           cand[5 + rows:5 + rows + dkp][None, :])
     admit = est[0] >= 2                     # one-hit-wonder filter, strict
     do_ins = push & (vic[MT_META] != _I32_MAX) & admit
-    candrow = jnp.concatenate(
-        [jnp.stack([cand[WT_LO], cand[WT_HI], t]),
-         cand[5:5 + rows], cand[5 + rows:5 + rows + dkp]]).astype(jnp.int32)
+    candrow = _main_record(_with_meta(cand, t))
     in1 = do_ins & (tslot < A)
     in2 = do_ins & (tslot >= A)
     j1 = jnp.minimum(tslot, A - 1)
@@ -1811,7 +1845,7 @@ def _one_access_set_s3fifo(spec: StepSpec, params: jnp.ndarray, state: dict,
 
 
 def _one_access_set_arc(spec: StepSpec, params: jnp.ndarray, state: dict,
-                        klo, khi, kidx, kdkb, kwset, kmset):
+                        klo, khi, kidx, kdkb, kwset, kmset, krec):
     """One access under the ``"arc"`` competitor policy.
 
     ARC (seed ``core.policies.ARC`` is the reference twin) on the shared
@@ -1949,8 +1983,7 @@ def _one_access_set_arc(spec: StepSpec, params: jnp.ndarray, state: dict,
 
     # -- insert: ghost-remembered keys go to T2, fresh keys to T1 MRU --------
     meta0 = jnp.where(gb1 | gb2, _PROT | mst, mst)
-    candrow = jnp.concatenate(
-        [jnp.stack([klo, khi, meta0]), kidx, kdkb]).astype(jnp.int32)
+    candrow = _main_record(_with_meta(krec, meta0))
     in1 = do_ins & (tslot < A)
     in2 = do_ins & (tslot >= A)
     j1 = jnp.minimum(tslot, A - 1)
@@ -1975,7 +2008,7 @@ def _one_access_set_arc(spec: StepSpec, params: jnp.ndarray, state: dict,
 
 
 def _one_access_set_lfu(spec: StepSpec, params: jnp.ndarray, state: dict,
-                        klo, khi, kidx, kdkb, kwset, kmset):
+                        klo, khi, kidx, kdkb, kwset, kmset, krec):
     """One access under the ``"lfu"`` competitor policy.
 
     Heap-free sketch-LFU (Shah/Mitra/Matani's O(1) LFU, mapped onto the
@@ -2037,8 +2070,7 @@ def _one_access_set_lfu(spec: StepSpec, params: jnp.ndarray, state: dict,
     tslot = jnp.argmin(okey2)
     miss = ~hit
     do_ins = miss & (okey1[tslot] != _I32_MAX)          # always admit
-    candrow = jnp.concatenate(
-        [jnp.stack([klo, khi, mst]), kidx, kdkb]).astype(jnp.int32)
+    candrow = _main_record(_with_meta(krec, mst))
     in1 = do_ins & (tslot < A)
     in2 = do_ins & (tslot >= A)
     j1 = jnp.minimum(tslot, A - 1)
@@ -2060,28 +2092,26 @@ def _one_access_set_lfu(spec: StepSpec, params: jnp.ndarray, state: dict,
 
 
 def _one_access(spec: StepSpec, params: jnp.ndarray, state: dict,
-                klo, khi, kidx, kdkb, kwset, kmset):
+                klo, khi, kidx, kdkb, kwset, kmset, krec):
     """Advance the cache state by one access; returns (state, hit).
 
     Dispatch is static (Python, at trace time): ``spec.assoc is None``
     takes the flat exact path, otherwise ``spec.policy`` selects which
-    admission/victim rules run on the set-associative machinery.  The
-    default ``"wtinylfu"`` path is byte-for-byte the pre-panel program
-    (tests/test_policy_panel.py pins its lowered HLO).
+    admission/victim rules run on the set-associative machinery.
+    ``policy="wtinylfu"`` lowers the same program as the default spec, and
+    the R7 digests in ``analysis/fingerprints.json`` pin that program
+    (tests/test_policy_panel.py).  The set path takes the access's packed
+    record ``krec`` (:func:`access_records`); the flat path has no records
+    and ignores it.
     """
     if spec.assoc is None:
         return _one_access_flat(spec, params, state, klo, khi, kidx, kdkb)
-    if spec.policy == "s3fifo":
-        return _one_access_set_s3fifo(spec, params, state, klo, khi, kidx,
-                                      kdkb, kwset, kmset)
-    if spec.policy == "arc":
-        return _one_access_set_arc(spec, params, state, klo, khi, kidx,
-                                   kdkb, kwset, kmset)
-    if spec.policy == "lfu":
-        return _one_access_set_lfu(spec, params, state, klo, khi, kidx,
-                                   kdkb, kwset, kmset)
-    return _one_access_set(spec, params, state, klo, khi, kidx, kdkb,
-                           kwset, kmset)
+    set_access = {"s3fifo": _one_access_set_s3fifo,
+                  "arc": _one_access_set_arc,
+                  "lfu": _one_access_set_lfu}.get(spec.policy,
+                                                  _one_access_set)
+    return set_access(spec, params, state, klo, khi, kidx, kdkb, kwset,
+                      kmset, krec)
 
 
 # ---------------------------------------------------------------------------
@@ -2197,7 +2227,7 @@ def _rebalance_set(spec: StepSpec, params, state, nq):
         u = mcap_new // nms + (s < mcap_new % nms).astype(jnp.int32)
         free = (meta == _EMPTY) & (way < u)
         j = jnp.argmax(free)
-        mainrow = jnp.concatenate([rec[:WT_META + 1], rec[WT_MSET2 + 1:]])
+        mainrow = _main_record(rec)
         row = jnp.where(free.any(), mainrow, blk[j])
         return jax.lax.dynamic_update_slice(
             mtab_c, blk.at[j].set(row), (s * A, 0))
@@ -2304,55 +2334,94 @@ def step_ref(spec: StepSpec, params: jnp.ndarray, state: dict,
     hi = hi.astype(jnp.int32)
     with jax.named_scope("probes"):
         kidx, kdkb, kwset, kmset = precompute_probes(spec, lo, hi)
-    # per-access inputs scan as 1-D columns: a (T, k) input makes the TPU
-    # relayout the whole array on every access to slice one row
-    cols = tuple(kidx.T), tuple(kdkb.T), tuple(kmset.T)
+    # the indices the body uses as scalars scan as 1-D columns: a (T, k)
+    # input makes the TPU relayout the whole array on every access to
+    # slice one row and split it into scalars
+    xs = (lo, hi, tuple(kidx.T), tuple(kdkb.T), kwset, tuple(kmset.T))
+    if n_valid is not None:
+        xs += (jnp.arange(b, dtype=jnp.int32),)
     with jax.named_scope("layout"):
         state = _scan_tables(spec, state, True)
+    # a set-path access reads its window record as one row of its block's
+    # packed records; under lanes (_LANE_TRACE) the body builds it from the
+    # (B,) lane vectors, which reads 4% faster there on a v5e; the flat
+    # path has none
+    packed = spec.assoc is not None and not _LANE_TRACE[0]
 
-    def access(carry, klo, khi, ki, kd, kw, km):
-        return _one_access(spec, params, carry, klo, khi, jnp.stack(ki),
-                           jnp.stack(kd), kw, jnp.stack(km))
+    def access(carry, klo, khi, ki, kd, kw, km, krec):
+        ki, kd, km = jnp.stack(ki), jnp.stack(kd), jnp.stack(km)
+        if krec is None and spec.assoc is not None:
+            krec = access_records(klo, khi, ki, kd, km)
+        return _one_access(spec, params, carry, klo, khi, ki, kd, kw, km,
+                           krec)
 
     if n_valid is None:
         # fast path: no tail masking, no per-step state merge
         def body(carry, x):
-            klo, khi, ki, kd, kw, km = x
-            return access(carry, klo, khi, ki, kd, kw, km)
+            return access(carry, *x)
+    else:
+        n_valid = jnp.asarray(n_valid, jnp.int32)
 
-        state, hits = jax.lax.scan(
-            body, state, (lo, hi, cols[0], cols[1], kwset, cols[2]),
-            unroll=unroll)
-        with jax.named_scope("layout"):
-            return _scan_tables(spec, state, False), hits
+        def body(carry, x):
+            *x, i, krec = x
+            new, hit = access(carry, *x, krec)
+            active = i < n_valid
+            merged = jax.tree_util.tree_map(
+                lambda n, o: jnp.where(active, n, o), new, carry)
+            return merged, jnp.where(active, hit, 0)
 
-    n_valid = jnp.asarray(n_valid, jnp.int32)
+    def scan(carry, x):
+        # the records are built once a block, not once a replay, so outside
+        # the once-a-replay `probes` scope
+        recs = None
+        if packed:
+            recs = access_records(x[0], x[1], jnp.stack(x[2], -1),
+                                  jnp.stack(x[3], -1), jnp.stack(x[5], -1))
+        return jax.lax.scan(body, carry, x + (recs,), unroll=unroll)
 
-    def body(carry, x):
-        klo, khi, ki, kd, kw, km, i = x
-        new, hit = access(carry, klo, khi, ki, kd, kw, km)
-        active = i < n_valid
-        merged = jax.tree_util.tree_map(
-            lambda n, o: jnp.where(active, n, o), new, carry)
-        return merged, jnp.where(active, hit, 0)
-
-    xs = (lo, hi, cols[0], cols[1], kwset, cols[2],
-          jnp.arange(b, dtype=jnp.int32))
-    state, hits = jax.lax.scan(body, state, xs, unroll=unroll)
+    if not packed or b <= _RECORD_BLOCK:
+        state, hits = scan(state, xs)
+    else:
+        state, hits = _scan_blocks(scan, state, xs, b)
     with jax.named_scope("layout"):
         return _scan_tables(spec, state, False), hits
+
+
+def _scan_blocks(scan, state, xs, n: int):
+    """``scan`` over ``n`` accesses a record block at a time: a loop over
+    whole :data:`_RECORD_BLOCK` blocks, then one call for the tail.  Blocks
+    are sliced from the columns and their hit flags written into one
+    ``(n,)`` buffer in place: a ``(blocks, K)`` reshape would relayout each
+    T-long column on the TPU.  Returns the state and the hit flags."""
+    K = _RECORD_BLOCK
+    nb, tail = divmod(n, K)
+
+    def block(j, carry):
+        state, hits = carry
+        x = jax.tree.map(
+            lambda c: jax.lax.dynamic_slice_in_dim(c, j * K, K), xs)
+        state, h = scan(state, x)
+        return state, jax.lax.dynamic_update_slice_in_dim(hits, h, j * K, 0)
+
+    state, hits = jax.lax.fori_loop(
+        0, nb, block, (state, jnp.zeros((n,), jnp.int32)))
+    if tail:
+        state, h = scan(state, jax.tree.map(lambda c: c[nb * K:], xs))
+        hits = jax.lax.dynamic_update_slice_in_dim(hits, h, nb * K, 0)
+    return state, hits
 
 
 # ---------------------------------------------------------------------------
 # fused Pallas kernel: whole chunk, state pinned in VMEM, buffers donated
 # ---------------------------------------------------------------------------
 
-# number of streamed (non-state) VMEM inputs: lo, hi, kidx, kdkb, kwset, kmset
-_N_STREAM = 6
+# number of streamed (non-state) VMEM inputs: lo, hi, kidx, kdkb, kwset,
+# kmset, recs (the packed records of access_records)
+_N_STREAM = 7
 
 
 def _step_kernel(spec: StepSpec, lo_ref, hi_ref, kidx_ref, kdkb_ref,
-                 kwset_ref, kmset_ref, scal_ref, *refs):
+                 kwset_ref, kmset_ref, recs_ref, scal_ref, *refs):
     keys = _state_keys(spec)
     n_state = len(keys)
     in_refs = refs[:n_state]
@@ -2367,6 +2436,7 @@ def _step_kernel(spec: StepSpec, lo_ref, hi_ref, kidx_ref, kdkb_ref,
     kdkb = kdkb_ref[...]
     kwset = kwset_ref[...]
     kmset = kmset_ref[...]
+    recs = recs_ref[...]
     state0 = _scan_tables(spec, {k: r[...] for k, r in zip(keys, in_refs)},
                           True)
     hits0 = jnp.zeros(lo.shape, jnp.int32)
@@ -2374,7 +2444,7 @@ def _step_kernel(spec: StepSpec, lo_ref, hi_ref, kidx_ref, kdkb_ref,
     def body(i, carry):
         state, hits = carry
         new, hit = _one_access(spec, params, state, lo[i], hi[i],
-                               kidx[i], kdkb[i], kwset[i], kmset[i])
+                               kidx[i], kdkb[i], kwset[i], kmset[i], recs[i])
         return new, hits.at[i].set(hit)
 
     state, hits = jax.lax.fori_loop(0, n_valid, body, (state0, hits0))
@@ -2404,6 +2474,7 @@ def step_pallas(spec: StepSpec, params: jnp.ndarray, state: dict,
     lo = lo.astype(jnp.int32)
     hi = hi.astype(jnp.int32)
     kidx, kdkb, kwset, kmset = precompute_probes(spec, lo, hi)
+    recs = access_records(lo, hi, kidx, kdkb, kmset)
     scal = jnp.concatenate([
         params.astype(jnp.int32),
         jnp.asarray(n_valid, jnp.int32).reshape(1)])
@@ -2425,6 +2496,6 @@ def step_pallas(spec: StepSpec, params: jnp.ndarray, state: dict,
         # donate every state buffer: input i+_N_STREAM+1 -> output i
         input_output_aliases={i + _N_STREAM + 1: i for i in range(n_state)},
         interpret=interpret,
-    )(lo, hi, kidx, kdkb, kwset, kmset, scal, *state_vals)
+    )(lo, hi, kidx, kdkb, kwset, kmset, recs, scal, *state_vals)
     new_state = dict(zip(keys, outs[:n_state]))
     return new_state, outs[n_state]

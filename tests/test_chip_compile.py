@@ -15,10 +15,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro.analysis.hlo_cost import _split_computations
+from repro.analysis.program_lint import (_find_whiles, _max_out_elems,
+                                         _reachable)
 from repro.core.device_simulate import (DeviceWTinyLFU, _jit_step,
                                         _sharded_runner)
 from repro.kernels import ops
-from repro.kernels.sketch_step import init_step_state
+from repro.kernels.sketch_step import _RECORD_BLOCK, init_step_state
 
 TRACE = 1 << 16
 
@@ -67,6 +70,38 @@ def test_engine_step_compiles(one_chip, cfg, trace_shape):
     spec, (params, state, lo, hi) = _engine_args(one_chip, cfg, trace_shape)
     compiled = _jit_step.lower(spec, params, state, lo, hi).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
+
+
+@pytest.mark.parametrize("trace, budget", [
+    (TRACE, 1 << 30),
+    # 32M accesses: the scalar columns alone take ~1.75 GB; a record
+    # buffer as long as the trace would add 16 GB at 512 B an access
+    (1 << 25, 2 << 30),
+], ids=["T2^16", "T2^25"])
+def test_kv64k_step_reads_its_record_as_one_row(one_chip, trace, budget):
+    """The ``kv-64k`` cell's step (C=65536, assoc=8, streams=1): each access
+    reads its packed window record as a row slice of its block's ``(K,
+    wcols)`` records, K = ``_RECORD_BLOCK``.  The window phase builds no
+    record from scalars (no ``concatenate`` under its scope), nothing
+    block-sized is copied inside the access scan, and the program's temp
+    stays within ``budget`` however long the trace."""
+    cfg = DeviceWTinyLFU(65536, assoc=8, sample_factor=8, window_frac=0.01)
+    spec, args = _engine_args(one_chip, cfg, (trace,))
+    compiled = _jit_step.lower(spec, *args).compile()
+    comps, _ = _split_computations(compiled.as_text())
+    (body,) = [b for _, _, trips, b in _find_whiles(comps)
+               if trips == _RECORD_BLOCK]
+    ops = [(comps[c], op) for c in _reachable(comps, [body])
+           for op in comps[c].ops.values()]
+    assert not [op.name for _, op in ops if op.kind == "concatenate"
+                and "/window/" in op.line]
+    reads = [op.out_shapes for comp, op in ops if op.kind == "dynamic-slice"
+             and comp.ops[op.operands[0]].out_shapes
+             == [("s32", (_RECORD_BLOCK, spec.wcols))]]
+    assert reads and all(r == [("s32", (1, spec.wcols))] for r in reads)
+    assert not [op.name for _, op in ops
+                if op.kind == "copy" and _max_out_elems(op) >= _RECORD_BLOCK]
+    assert compiled.memory_analysis().temp_size_in_bytes < budget
 
 
 def test_sharded_epoch_runner_compiles(one_chip):

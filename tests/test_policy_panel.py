@@ -31,12 +31,15 @@ import pytest
 
 from _hypothesis_compat import given, settings, strategies as st
 
-from repro.core.device_simulate import (DeviceWTinyLFU, _row_extra,
+from repro.core.device_simulate import (DeviceWTinyLFU, _jit_step,
+                                        _row_extra, _trace_lanes,
                                         simulate_trace, simulate_sweep)
 from repro.core.policies import SetAssocARC, SetAssocLFU, SetAssocS3FIFO
+from repro.core.wtinylfu import WTinyLFU
 from repro.kernels.sketch_common import POLICIES
-from repro.kernels.sketch_step import (StepSpec, _EMPTY, _I32_MAX, MT_LO,
-                                       MT_HI, MT_META, WT_META,
+from repro.kernels.sketch_step import (StepSpec, _EMPTY, _I32_MAX,
+                                       _RECORD_BLOCK, MT_LO,
+                                       MT_HI, MT_META, R_SIZE, WT_META,
                                        init_step_state, step_ref)
 from repro.traces import panel_traces, zipf_trace
 from repro.traces.synthetic import zipf_probs, _sample_from_probs
@@ -136,6 +139,83 @@ class TestDeviceTwinParity:
         twin = SetAssocARC(self.C, assoc=8, dk_bits=spec_bits, dk_probes=3)
         dev = _device_hits(cfg, self.TRACE)
         assert np.array_equal(dev, self._twin_hits(twin))
+
+    @pytest.mark.parametrize("streams", [1, 4])
+    @pytest.mark.parametrize("policy", POLICIES)
+    def test_pallas_equals_scan_equals_twin(self, policy, streams):
+        """Every set-path policy builds its table records from the packed
+        access record: the fused kernel (fed the records) equals the scan
+        (records scanned at streams=1, built per access under lanes) in
+        state and hit flags, and each lane's hits equal its host twin's,
+        over 1,500 accesses that cross the §3.3 reset (W = 8 C = 480)."""
+        n = 1_500
+        traces = np.stack([zipf_trace(n, n_items=600, alpha=0.9, seed=s)
+                           for s in range(21, 21 + streams)])
+        free = {} if policy == "arc" else self.FREE
+        kw = dict(assoc=8, policy=policy, window_frac=_wf(policy), **free)
+        twins = {"wtinylfu": lambda: WTinyLFU(self.C, window_frac=0.01,
+                                              assoc=8, **free),
+                 "s3fifo": lambda: SetAssocS3FIFO(self.C, window_frac=0.1,
+                                                  assoc=8, **free),
+                 "lfu": lambda: SetAssocLFU(self.C, assoc=8, **free),
+                 "arc": lambda: SetAssocARC(
+                     self.C, assoc=8, dk_probes=3,
+                     dk_bits=DeviceWTinyLFU(self.C, **kw).dk_bits)}
+        run = {}
+        for backend in ("jit", "pallas"):
+            _, state, hits = simulate_trace(
+                traces if streams > 1 else traces[0], self.C,
+                streams=streams, backend=backend, chunk=512,
+                return_state=True, **kw)
+            run[backend] = (state, np.asarray(hits).reshape(streams, n))
+        (s_ref, h_ref), (s_pal, h_pal) = run["jit"], run["pallas"]
+        for k in s_ref:
+            np.testing.assert_array_equal(np.asarray(s_ref[k]),
+                                          np.asarray(s_pal[k]),
+                                          err_msg=f"state[{k!r}]")
+        np.testing.assert_array_equal(h_ref, h_pal)
+        if policy != "arc":                 # the sketch policies reset
+            sizes = np.asarray(s_ref["regs"]).reshape(streams, -1)[:, R_SIZE]
+            assert (sizes < n).all()
+        for b in range(streams):
+            twin = twins[policy]()
+            np.testing.assert_array_equal(
+                h_ref[b], [twin.access(int(k)) for k in traces[b]],
+                err_msg=f"lane {b} vs host twin")
+
+    @pytest.mark.parametrize("streams, masked", [(1, False), (2, True)])
+    @pytest.mark.parametrize("policy", POLICIES)
+    def test_record_blocks_equal_one_block_chunks(self, policy, streams,
+                                                  masked):
+        """A step over two whole record blocks and a tail (the block loop
+        and the tail scan) equals the same accesses stepped one block per
+        call, in final state and hit flags; with ``n_valid`` masking the
+        last 77 accesses too, where a masked chunk leaves its state as
+        it found it."""
+        n = 2 * _RECORD_BLOCK + 300
+        nv = n - 77 if masked else None
+        traces = np.stack([zipf_trace(n, n_items=600, alpha=0.9, seed=s)
+                           for s in range(31, 31 + streams)])
+        free = {} if policy == "arc" else self.FREE
+        cfg = DeviceWTinyLFU(self.C, assoc=8, policy=policy, streams=streams,
+                             window_frac=_wf(policy), **free)
+        spec = cfg.spec()
+        state = init_step_state(spec, cfg.window_cap, cfg.main_cap)
+        lo, hi = _trace_lanes(traces if streams > 1 else traces[0])
+        whole, hits = _jit_step(spec, cfg.params(), state, lo, hi, nv)
+        parts = []
+        for a in range(0, n, _RECORD_BLOCK):
+            b = min(a + _RECORD_BLOCK, n)
+            part_nv = None if nv is None else max(0, min(b, nv) - a)
+            state, h = _jit_step(spec, cfg.params(), state, lo[..., a:b],
+                                 hi[..., a:b], part_nv)
+            parts.append(np.asarray(h))
+        np.testing.assert_array_equal(np.asarray(hits),
+                                      np.concatenate(parts, axis=-1))
+        for k in whole:
+            np.testing.assert_array_equal(np.asarray(whole[k]),
+                                          np.asarray(state[k]),
+                                          err_msg=f"state[{k!r}]")
 
 
 # ===========================================================================
